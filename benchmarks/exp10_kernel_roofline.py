@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.backend import interpret_kernels
 from repro.core.fcdcc import CodedConv2d
 from repro.core.pipeline import plan_layers
 from repro.kernels import autotune
@@ -168,7 +169,7 @@ def run(quick: bool = True, smoke: bool = False, update: bool = True):
         bench["runs"].append({
             "date": time.strftime("%Y-%m-%d"),
             "backend": jax.default_backend(),
-            "interpret": True,
+            "interpret": interpret_kernels(),
             "quick": quick,
             "cells": cells,
         })

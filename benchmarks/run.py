@@ -21,6 +21,10 @@ def main() -> None:
     quick = not args.full
     only = set(args.only.split(",")) if args.only else None
 
+    from repro.backend import enable_compile_cache
+
+    enable_compile_cache()
+
     from . import (
         exp1_naive_vs_fcdcc,
         exp2_stability,

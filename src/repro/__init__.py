@@ -1,8 +1,2 @@
-"""repro: FCDCC coded distributed convolution + the serving/training substrate.
-
-Importing the package installs the jax version-compat shims (``repro.compat``)
-so code written against the modern mesh API runs on jax 0.4.x too.
-"""
-from . import compat as _compat
-
-_compat.install()
+"""repro: FCDCC coded distributed convolution + the serving/training
+substrate."""

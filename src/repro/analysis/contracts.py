@@ -125,7 +125,6 @@ def build_pipeline(cfg: ContractConfig):
         default_kab=cfg.kab,
         input_hw=input_hw(cfg.arch, smoke=True),
         backend=cfg.backend,
-        interpret=True,
         bucket_sizes=cfg.buckets,
         fuse_transitions=cfg.fused,
         donate_transitions=True,
@@ -374,7 +373,7 @@ def build_decoder_pipeline(cfg: DecoderContractConfig):
     return build_lm_decoder_pipeline(
         bundle.cfg, params, cfg.n,
         k_b=None if plan else cfg.k_b, plan=plan,
-        backend=cfg.backend, interpret=True,
+        backend=cfg.backend,
         bucket_sizes=cfg.buckets, max_len=32,
     )
 

@@ -13,7 +13,7 @@ from typing import Any, Iterator
 
 import jax
 import numpy as np
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 ClosedJaxpr = jax_core.ClosedJaxpr
 Jaxpr = jax_core.Jaxpr

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.backend import full_f32
+
 from .crme import recovery_matrix
 from .fcdcc import FcdccPlan
 from .nsctc import encode_tensor_list, group_by_worker
@@ -129,7 +131,7 @@ class _GemmRound:
         self.worker_compute = worker_compute
 
 
-def _make_worker_compute(backend: str, interpret: bool):
+def _make_worker_compute(backend: str):
     """The ONE plan-agnostic coded GEMM worker program.
 
     ``xe_i``: (ell_a=1, B, d_in) — the broadcast activation share;
@@ -144,15 +146,17 @@ def _make_worker_compute(backend: str, interpret: bool):
     if backend == "pallas":
         from repro.kernels.matmul.ops import matmul
 
+        @full_f32
         def worker_compute(xe_i, ke_i):
             eb, d_in, ob = ke_i.shape
             # one MXU GEMM for all ell_b coded column blocks
             kcat = jnp.transpose(ke_i, (1, 0, 2)).reshape(d_in, eb * ob)
-            y = matmul(xe_i[0], kcat, interpret=interpret)
+            y = matmul(xe_i[0], kcat)
             return jnp.transpose(y.reshape(y.shape[0], eb, ob), (1, 0, 2))
 
         return worker_compute
 
+    @full_f32
     def worker_compute(xe_i, ke_i):
         y = jnp.einsum("abd,cdo->acbo", xe_i, ke_i)
         return y.reshape((-1,) + y.shape[2:])
@@ -176,7 +180,6 @@ class CodedDecoderPipeline:
     """
 
     def __init__(self, cfg, params, plan, *, backend: str = "lax",
-                 interpret: bool = True,
                  bucket_sizes: Sequence[int] | None = None,
                  max_len: int | None = None):
         if cfg.attn != "gqa":
@@ -192,7 +195,6 @@ class CodedDecoderPipeline:
         self.plan = plan
         self.n = plan.n
         self.backend = backend
-        self.interpret = interpret
         self.pool = None
         self.devices = None
         self.fuse_transitions = False  # GEMM rounds have no fused transitions
@@ -223,7 +225,7 @@ class CodedDecoderPipeline:
 
         # compile the round specs and encode weights exactly once ---------
         self.weight_encode_calls = 0
-        compute = _make_worker_compute(backend, interpret)
+        compute = _make_worker_compute(backend)
         self.specs: list[GemmRoundSpec] = []
         self.layers: list[_GemmRound] = []
         self.coded_filters: list[jnp.ndarray] = []
@@ -262,6 +264,7 @@ class CodedDecoderPipeline:
         self._prefill_fn = None
 
     # -- weight encoding (once, at construction) ---------------------------
+    @full_f32
     def _encode_weights(self, w: jnp.ndarray) -> jnp.ndarray:
         """(d_in, d_out) -> resident coded columns (n, ell_b, d_in, ob)."""
         self.weight_encode_calls += 1
@@ -394,7 +397,7 @@ class CodedDecoderPipeline:
                     outs.shape[2], q * outs.shape[3]
                 )
 
-            self._decoder = jax.jit(dec)
+            self._decoder = jax.jit(full_f32(dec))
         return self._decoder
 
     def decoder(self, idx: int, worker_ids: tuple[int, ...]):
@@ -446,7 +449,7 @@ class CodedDecoderPipeline:
                 return jax.lax.dynamic_slice_in_dim(c, row, 1, axis=0)
         else:
             raise KeyError(name)
-        fn = self._glue[name] = jax.jit(raw)
+        fn = self._glue[name] = jax.jit(full_f32(raw))
         return fn
 
     def attn_fn(self, layer: int):
@@ -492,7 +495,7 @@ class CodedDecoderPipeline:
             ctx = _attend(q, ckb, cvb, pos[:, None], k_pos, cfg, window)
             return ctx.reshape(b, h * hd), ck, cv
 
-        fn = self._attn_fns[window] = jax.jit(raw)
+        fn = self._attn_fns[window] = jax.jit(full_f32(raw))
         return fn
 
     # -- KV slot cache ------------------------------------------------------
@@ -533,7 +536,7 @@ class CodedDecoderPipeline:
                 logits, filled = lm.prefill(params, cfg, cache, tokens)
                 return logits, filled["dense"]["k"], filled["dense"]["v"]
 
-            self._prefill_fn = jax.jit(raw)
+            self._prefill_fn = jax.jit(full_f32(raw))
         return self._prefill_fn(self.params, prompts)
 
     # -- decode-step drivers -------------------------------------------------
@@ -703,7 +706,6 @@ def build_lm_decoder_pipeline(
     k_b: int | None = None,
     plan=None,
     backend: str = "lax",
-    interpret: bool = True,
     bucket_sizes: Sequence[int] | None = None,
     max_len: int | None = None,
 ) -> CodedDecoderPipeline:
@@ -718,6 +720,6 @@ def build_lm_decoder_pipeline(
     if plan.n != n:
         raise ValueError(f"plan targets n={plan.n}, requested n={n}")
     return CodedDecoderPipeline(
-        cfg, params, plan, backend=backend, interpret=interpret,
+        cfg, params, plan, backend=backend,
         bucket_sizes=bucket_sizes, max_len=max_len,
     )

@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.backend import full_f32
+
 from .crme import CrmeAxisCode, make_axis_codes, next_odd, recovery_matrix
 from .nsctc import decode_blocks, encode_tensor_list, group_by_worker
 from .partition import (
@@ -76,17 +78,15 @@ class FcdccPlan:
         return self.n - self.delta
 
 
-def _conv_valid(x, k, stride, backend="lax", interpret=True):
+def _conv_valid(x, k, stride, backend="lax"):
     """VALID conv of one coded block pair: x ([B,]C,H,W) * k (N,C,KH,KW)."""
     batched = x.ndim == 4
     if backend == "pallas":
         from repro.kernels.conv2d.ops import conv2d_im2col
 
         if batched:
-            return jax.vmap(
-                lambda xi: conv2d_im2col(xi, k, stride, interpret=interpret)
-            )(x)
-        return conv2d_im2col(x, k, stride, interpret=interpret)
+            return jax.vmap(lambda xi: conv2d_im2col(xi, k, stride))(x)
+        return conv2d_im2col(x, k, stride)
     y = jax.lax.conv_general_dilated(
         x if batched else x[None],
         k,
@@ -106,21 +106,20 @@ class CodedConv2d:
     """
 
     def __init__(self, plan: FcdccPlan, geo: ConvGeometry, backend: str = "lax",
-                 fused_worker: bool = True, interpret: bool = True):
+                 fused_worker: bool = True):
         if geo.k_a != plan.k_a or geo.k_b != plan.k_b:
             geo = dataclasses.replace(geo, k_a=plan.k_a, k_b=plan.k_b)
         self.plan = plan
         self.geo = geo
         self.backend = backend
         self.fused_worker = fused_worker
-        # pallas-only: True emulates kernels on CPU, False lowers to real TPU
-        self.interpret = interpret
         self.a_code, self.b_code = plan.codes
         # instrumentation: CodedPipeline/tests assert encode-once semantics
         self.filter_encode_calls = 0
         self.input_encode_calls = 0
 
     # -- master side: encode ---------------------------------------------
+    @full_f32
     def encode_inputs(self, x: jnp.ndarray, matrix=None) -> jnp.ndarray:
         """([B,]C,H,W) -> coded inputs (n, ell_a, [B,] C, h_hat, W+2p).
 
@@ -135,6 +134,7 @@ class CodedConv2d:
         )
         return group_by_worker(coded, self.a_code.ell)
 
+    @full_f32
     def encode_from_partitions(self, parts: jnp.ndarray, matrix=None) -> jnp.ndarray:
         """Encode pre-sliced APCP parts ``(k_a, [B,] C, h_hat, W+2p)``.
 
@@ -151,6 +151,7 @@ class CodedConv2d:
         )
         return group_by_worker(coded, self.a_code.ell)
 
+    @full_f32
     def encode_filters(self, k: jnp.ndarray) -> jnp.ndarray:
         """(N,C,KH,KW) -> coded filters (n, ell_b, N/k_b, C, KH, KW)."""
         self.filter_encode_calls += 1
@@ -159,6 +160,7 @@ class CodedConv2d:
         return group_by_worker(coded, self.b_code.ell)
 
     # -- worker side -------------------------------------------------------
+    @full_f32
     def worker_compute(self, xe_i: jnp.ndarray, ke_i: jnp.ndarray) -> jnp.ndarray:
         """Coded subtask of one worker (Algorithm 4 lines 6-11).
 
@@ -179,14 +181,13 @@ class CodedConv2d:
                 for b2 in range(self.plan.ell_b):
                     outs.append(
                         _conv_valid(xe_i[b1], ke_i[b2], self.geo.stride,
-                                    self.backend, self.interpret)
+                                    self.backend)
                     )
             return jnp.stack(outs, axis=0)
         if self.backend == "pallas":
             from repro.kernels.conv2d.ops import coded_worker
 
-            return coded_worker(xe_i, ke_i, self.geo.stride,
-                                interpret=self.interpret)
+            return coded_worker(xe_i, ke_i, self.geo.stride)
         ea, eb = self.plan.ell_a, self.plan.ell_b
         nb = ke_i.shape[1]
         k_cat = ke_i.reshape((eb * nb,) + ke_i.shape[2:])
@@ -208,6 +209,7 @@ class CodedConv2d:
         )
 
     # -- master side: decode ------------------------------------------------
+    @full_f32
     def decode_to_partitions(self, worker_ids, outputs: jnp.ndarray) -> jnp.ndarray:
         """Any-delta decode to the partition grid — merge deliberately
         skipped.

@@ -30,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.backend import full_f32
+
 from .cost import CostWeights, optimal_partition
 from .crme import recovery_matrix
 from .fcdcc import CodedConv2d, FcdccPlan
@@ -200,7 +202,6 @@ class CodedPipeline:
 
     def __init__(self, specs: Sequence[CodedLayerSpec], params: dict, *,
                  backend: str = "lax", fused_worker: bool = True,
-                 interpret: bool = True,
                  bucket_sizes: Sequence[int] | None = None,
                  fuse_transitions: bool = False,
                  donate_transitions: bool | None = None,
@@ -214,9 +215,6 @@ class CodedPipeline:
         self.specs = specs
         self.n = ns.pop()
         self.backend = backend
-        # pallas-only: interpret=True emulates the worker kernels on CPU,
-        # False lowers them to Mosaic for real TPU hardware
-        self.interpret = interpret
         # worker-pool preference carried to whichever FcdccCluster /
         # CodedServer adopts this pipeline (None = auto-select there);
         # the pipeline's own math never consults it
@@ -248,7 +246,7 @@ class CodedPipeline:
         )
         self.layers = [
             CodedConv2d(s.plan, s.geo, backend=backend,
-                        fused_worker=fused_worker, interpret=interpret)
+                        fused_worker=fused_worker)
             for s in specs
         ]
         # resident coded filters: encoded exactly once, reused every run
@@ -475,6 +473,7 @@ class CodedPipeline:
         if fn is None:
             q = spec.plan.k_a * spec.plan.k_b
 
+            @full_f32
             def dec(outs, d, _q=q, _geo=spec.geo, _pool=spec.pool):
                 rows = outs.reshape(outs.shape[0] * outs.shape[1], -1)
                 true_rows = d.astype(rows.dtype) @ rows
@@ -528,11 +527,8 @@ class CodedPipeline:
             if self.backend == "pallas":
                 from repro.kernels.conv2d.ops import coded_transition
 
-                interpret = self.interpret
-
                 def trans(outs, d, m_next):
-                    coded = coded_transition(outs, d, m_next, assemble,
-                                             interpret=interpret)
+                    coded = coded_transition(outs, d, m_next, assemble)
                     return group_by_worker(coded, ell_next)
             else:
                 def trans(outs, d, m_next):
@@ -546,7 +542,7 @@ class CodedPipeline:
                     return group_by_worker(coded, ell_next)
 
             fn = self._transitions[key] = jax.jit(
-                trans,
+                full_f32(trans),
                 donate_argnums=(0,) if self.donate_transitions else (),
             )
         return fn
@@ -588,11 +584,10 @@ class CodedPipeline:
                 xe = jax.eval_shape(layer.encode_inputs, x, m_sel)
                 ke_shape = self.coded_filters[idx].shape[1:]
                 wkey = autotune.worker_key(
-                    xe.shape[1:], ke_shape, spec.geo.stride,
-                    interpret=self.interpret)
+                    xe.shape[1:], ke_shape, spec.geo.stride)
                 tuned[wkey] = autotune.tune_worker(
                     xe.shape[1:], ke_shape, spec.geo.stride,
-                    interpret=self.interpret, dtype=self.input_dtype,
+                    dtype=self.input_dtype,
                     repeat=repeat, force=force, path=path)
                 outs = jax.eval_shape(
                     jax.vmap(layer.worker_compute),
@@ -604,12 +599,10 @@ class CodedPipeline:
                 if self.fuse_transitions and idx < last:
                     q = outs.shape[0] * outs.shape[1]
                     f = int(np.prod(outs.shape[2:]))
-                    dkey = autotune.matmul_key(q, q, f, relu=True,
-                                               interpret=self.interpret)
+                    dkey = autotune.matmul_key(q, q, f, relu=True)
                     tuned[dkey] = autotune.tune_matmul(
-                        q, q, f, relu=True, interpret=self.interpret,
-                        dtype=self.input_dtype, repeat=repeat, force=force,
-                        path=path)
+                        q, q, f, relu=True, dtype=self.input_dtype,
+                        repeat=repeat, force=force, path=path)
                     nxt = self.specs[idx + 1]
                     geo, pool, geo_next = spec.geo, spec.pool, nxt.geo
 
@@ -634,12 +627,10 @@ class CodedPipeline:
                         self.encode_columns_all(idx + 1).shape[1],
                     }
                     for width in sorted(widths):
-                        ekey = autotune.matmul_key(
-                            width, k2, fp, interpret=self.interpret)
+                        ekey = autotune.matmul_key(width, k2, fp)
                         tuned[ekey] = autotune.tune_matmul(
-                            width, k2, fp, interpret=self.interpret,
-                            dtype=self.input_dtype, repeat=repeat,
-                            force=force, path=path)
+                            width, k2, fp, dtype=self.input_dtype,
+                            repeat=repeat, force=force, path=path)
                 # next layer sees this layer's pooled output
                 x = jax.ShapeDtypeStruct(
                     (bucket, spec.geo.out_channels, spec.out_hw,
@@ -876,7 +867,6 @@ def build_cnn_pipeline(
     input_hw: int | None = None,
     weights: CostWeights = CostWeights(),
     backend: str = "lax",
-    interpret: bool = True,
     bucket_sizes: Sequence[int] | None = None,
     fuse_transitions: bool = False,
     donate_transitions: bool | None = None,
@@ -897,7 +887,7 @@ def build_cnn_pipeline(
         per_layer_kab=per_layer_kab,
         weights=weights,
     )
-    return CodedPipeline(specs, params, backend=backend, interpret=interpret,
+    return CodedPipeline(specs, params, backend=backend,
                          bucket_sizes=bucket_sizes,
                          fuse_transitions=fuse_transitions,
                          donate_transitions=donate_transitions,

@@ -17,9 +17,11 @@ a cache miss at trace time just returns None and the kernel uses its
 defaults.  Tile sizes are static kernel arguments, so a tuned program is
 the same single trace per (geometry, bucket) an untuned one would be.
 
-The ledger lives at ``results/autotune_cache.json`` by default (machine
-local, gitignored) — override with ``REPRO_AUTOTUNE_CACHE`` or the
-``path`` arguments.
+The ledger lives at ``kernels/autotune_ledger.json`` next to this module
+by default — a file git commits, so a run takes tuned tiles only from a
+ledger that was committed with the code (no such file: every kernel uses
+its default tiles).  Override with ``REPRO_AUTOTUNE_CACHE`` or the ``path``
+arguments.
 """
 from __future__ import annotations
 
@@ -31,6 +33,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.backend import interpret_kernels
 
 __all__ = [
     "cache_path", "clear_cache", "load_cache", "save_cache", "sweep_count",
@@ -58,27 +62,29 @@ MATMUL_CANDIDATES: tuple[dict, ...] = (
 def cache_path() -> str:
     return os.environ.get(
         "REPRO_AUTOTUNE_CACHE",
-        os.path.join("results", "autotune_cache.json"),
+        os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                     "autotune_ledger.json"),
     )
 
 
-def _backend_tag(interpret: bool) -> str:
+def _backend_tag(interpret: bool | None) -> str:
+    if interpret is None:
+        interpret = interpret_kernels()
     return f"{jax.default_backend()}/interpret={int(bool(interpret))}"
 
 
 def matmul_key(m: int, k: int, n: int, *, relu: bool = False,
-               interpret: bool = True) -> str:
+               interpret: bool | None = None) -> str:
     return (f"matmul/{_backend_tag(interpret)}/"
             f"m{m}k{k}n{n}/relu={int(bool(relu))}")
 
 
-def worker_key(xe_shape: tuple, ke_shape: tuple, stride: int, *,
-               interpret: bool = True) -> str:
+def worker_key(xe_shape: tuple, ke_shape: tuple, stride: int) -> str:
     """Cell key for one worker subtask: coded-share and filter-group shapes
     (the batch dim rides inside ``xe_shape``, so buckets key separately)."""
     xs = "x".join(map(str, xe_shape))
     ks = "x".join(map(str, ke_shape))
-    return f"worker/{_backend_tag(interpret)}/xe{xs}/ke{ks}/s{stride}"
+    return f"worker/{_backend_tag(None)}/xe{xs}/ke{ks}/s{stride}"
 
 
 # -- ledger ----------------------------------------------------------------
@@ -145,16 +151,15 @@ def _record(key: str, params: dict, us: float, swept: list, path=None) -> None:
 
 # -- trace-time lookups (never sweep) --------------------------------------
 def matmul_params(m: int, k: int, n: int, *, relu: bool = False,
-                  interpret: bool = True) -> dict | None:
+                  interpret: bool | None = None) -> dict | None:
     """Tuned ``matmul_pallas`` kwargs for this GEMM cell, or None."""
     return _lookup(matmul_key(m, k, n, relu=relu, interpret=interpret))
 
 
-def worker_params(xe_shape: tuple, ke_shape: tuple, stride: int, *,
-                  interpret: bool = True) -> dict | None:
+def worker_params(xe_shape: tuple, ke_shape: tuple,
+                  stride: int) -> dict | None:
     """Tuned ``coded_worker_pallas`` kwargs for this worker cell, or None."""
-    return _lookup(worker_key(xe_shape, ke_shape, stride,
-                              interpret=interpret))
+    return _lookup(worker_key(xe_shape, ke_shape, stride))
 
 
 # -- timing ----------------------------------------------------------------
@@ -170,15 +175,14 @@ def _time_best(fn, args, repeat: int) -> float:
 
 # -- sweeps ----------------------------------------------------------------
 def tune_matmul(m: int, k: int, n: int, *, relu: bool = False,
-                interpret: bool = True, dtype=jnp.float32,
-                candidates=None, repeat: int = 3, force: bool = False,
-                path: str | None = None) -> dict:
+                dtype=jnp.float32, candidates=None, repeat: int = 3,
+                force: bool = False, path: str | None = None) -> dict:
     """Sweep ``matmul_pallas`` configs for an (m, k, n) cell; cache winner.
 
     Returns the winning kwargs.  A cached cell returns instantly without
     sweeping unless ``force``.
     """
-    key = matmul_key(m, k, n, relu=relu, interpret=interpret)
+    key = matmul_key(m, k, n, relu=relu)
     if not force:
         hit = _lookup(key)
         if hit is not None:
@@ -193,7 +197,7 @@ def tune_matmul(m: int, k: int, n: int, *, relu: bool = False,
     for cand in candidates or MATMUL_CANDIDATES:
         us = _time_best(
             lambda a_, b_, c=dict(cand): matmul_pallas(
-                a_, b_, relu=relu, interpret=interpret, **c),
+                a_, b_, relu=relu, **c),
             (a, b), repeat,
         )
         swept.append({"params": dict(cand), "us": round(us, 2)})
@@ -223,9 +227,8 @@ def worker_candidates(xe_shape: tuple, ke_shape: tuple,
 
 
 def tune_worker(xe_shape: tuple, ke_shape: tuple, stride: int, *,
-                interpret: bool = True, dtype=jnp.float32, candidates=None,
-                repeat: int = 3, force: bool = False,
-                path: str | None = None) -> dict:
+                dtype=jnp.float32, candidates=None, repeat: int = 3,
+                force: bool = False, path: str | None = None) -> dict:
     """Sweep the coded-worker kernel for one (shapes, stride) cell.
 
     ``xe_shape``: one worker's coded input shares ``(ell_a, [B,] C, h_hat,
@@ -233,7 +236,7 @@ def tune_worker(xe_shape: tuple, ke_shape: tuple, stride: int, *,
     The sweep covers both im2col strategies, so the tuned path is never
     slower than either default.
     """
-    key = worker_key(xe_shape, ke_shape, stride, interpret=interpret)
+    key = worker_key(xe_shape, ke_shape, stride)
     if not force:
         hit = _lookup(key)
         if hit is not None:
@@ -248,7 +251,7 @@ def tune_worker(xe_shape: tuple, ke_shape: tuple, stride: int, *,
     for cand in candidates or worker_candidates(xe_shape, ke_shape, stride):
         fn = jax.jit(
             lambda x, k, c=dict(cand): coded_worker_pallas(
-                x, k, stride, interpret=interpret, **c)
+                x, k, stride, **c)
         )
         us = _time_best(fn, (xe, ke), repeat)
         swept.append({"params": dict(cand), "us": round(us, 2)})

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.backend import interpret_kernels
 from repro.kernels.matmul.kernel import matmul_pallas
 
 __all__ = ["coded_gemm_pallas", "coded_gemm_pallas_legacy"]
@@ -28,7 +29,7 @@ def coded_gemm_pallas(
     code: jnp.ndarray,
     feats: jnp.ndarray,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     bm: int = 128,
     bn: int = 512,
     bk: int = 128,
@@ -49,7 +50,8 @@ def coded_gemm_pallas(
 
 def _coded_kernel(m_ref, t_ref, o_ref):
     o_ref[...] = jnp.dot(
-        m_ref[...], t_ref[...], preferred_element_type=jnp.float32
+        m_ref[...], t_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     ).astype(o_ref.dtype)
 
 
@@ -59,10 +61,11 @@ def coded_gemm_pallas_legacy(
     feats: jnp.ndarray,
     *,
     bf: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """The pre-rebase lowering (feature-axis grid only): kept as the
     bit-parity reference for the matmul-backed path."""
+    interpret = interpret_kernels() if interpret is None else interpret
     r_out, r_in = code.shape
     r_in2, f = feats.shape
     assert r_in == r_in2

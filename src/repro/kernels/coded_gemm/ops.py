@@ -14,33 +14,33 @@ from .kernel import coded_gemm_pallas
 __all__ = ["crme_encode", "crme_decode", "coded_gemm"]
 
 
-def _tuned(m: int, k: int, n: int, interpret: bool) -> dict:
-    params = autotune.matmul_params(m, k, n, interpret=interpret)
+def _tuned(m: int, k: int, n: int) -> dict:
+    params = autotune.matmul_params(m, k, n)
     if not params:
         return {}
     return {k_: v for k_, v in params.items()
             if k_ in ("bm", "bn", "bk", "num_buffers")}
 
 
-def coded_gemm(code, feats, *, interpret=True, **kw):
+def coded_gemm(code, feats, **kw):
     if not kw:
-        kw = _tuned(code.shape[0], code.shape[1], feats.shape[1], interpret)
-    return coded_gemm_pallas(code, feats, interpret=interpret, **kw)
+        kw = _tuned(code.shape[0], code.shape[1], feats.shape[1])
+    return coded_gemm_pallas(code, feats, **kw)
 
 
-def crme_encode(parts, matrix, *, interpret=True):
+def crme_encode(parts, matrix):
     """``parts`` (k, *block), ``matrix`` (k, ell*n) -> (ell*n, *block)."""
     k = parts.shape[0]
     rows = parts.reshape(k, -1)
     m = jnp.asarray(matrix, dtype=parts.dtype)
-    out = coded_gemm(m.T, rows, interpret=interpret)
+    out = coded_gemm(m.T, rows)
     return out.reshape((m.shape[1],) + parts.shape[1:])
 
 
-def crme_decode(decode_matrix, coded, *, interpret=True):
+def crme_decode(decode_matrix, coded):
     """``decode_matrix`` (Q, Q) = inv(E^T); ``coded`` (Q, *block)."""
     q = coded.shape[0]
     rows = coded.reshape(q, -1)
     d = jnp.asarray(decode_matrix, dtype=coded.dtype)
-    out = coded_gemm(d, rows, interpret=interpret)
+    out = coded_gemm(d, rows)
     return out.reshape(coded.shape)

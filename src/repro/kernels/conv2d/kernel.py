@@ -2,29 +2,35 @@
 
 Hardware adaptation (DESIGN.md §3): the paper's workers run a black-box CPU
 convolution; on TPU the native form is im2col followed by an MXU-tiled GEMM.
-The GEMM dims are ``M = H'*W'`` (output pixels), ``K = C*K_H*K_W`` (patch),
-``N = out channels``.
+The GEMM dims are ``M = H'*W'`` (output pixels), ``K = KH*KW*C`` (patch,
+tap-major), ``N = out channels``.
 
 Two im2col strategies:
 
   * **In-kernel im2col** (``fused_im2col=True``, the default) — patch
-    extraction is fused into the GEMM tile load: the grid walks (image
-    share, output-row tile, N tile), each step pulls one padded input share
-    into VMEM via ``BlockSpec`` streaming and gathers its ``C*KH*KW`` patch
-    rows *inside* the kernel (static shifted slices over the share — pure
-    register traffic), so the ``(ea*B, C*KH*KW, H', W')`` patch tensor —
-    the largest intermediate on the worker hot path — never exists in HBM.
-    When the whole share is too big for VMEM (uncoded full-frame convs),
-    the **K-streamed** variant keeps the share in HBM and double-buffers
-    per-K-chunk channel windows in via async copies instead
-    (``stream_k``); it accumulates the same fp32 chunks in the same order,
-    so it is bit-identical to the resident variant.
+    extraction is fused into the GEMM tile load.  The wrapper lays each
+    input share out as channel-last stride-phase planes
+    (``_space_to_depth``), so every tap of every output pixel is a
+    unit-stride window and channels ride the lane axis.  The grid walks
+    (image share, output-row tile, N tile); at the first N tile of a row
+    tile the kernel copies one ``(bo*W', C)`` window per tap into a VMEM
+    patch scratch (plain 2-D window stores, which Mosaic lowers — no
+    dynamic or strided value slices, no 4-D->2-D shape casts), and every N
+    tile sweeps that scratch against its filters.  The
+    ``(ea*B, C*KH*KW, H', W')`` patch tensor — the largest intermediate on
+    the worker hot path — never exists in HBM.  When the whole share is
+    too big for VMEM (uncoded full-frame convs), the **streamed** variant
+    (``stream_k``) keeps the planes in HBM and copies in only each row
+    tile's plane rows; the patch and the fp32 chunk order are the same, so
+    it is bit-identical to the resident variant.
   * **Two-step** (``fused_im2col=False``, the fallback for odd geometries)
     — XLA's ``conv_general_dilated_patches`` materializes the patch tensor
-    in HBM, then one ``matmul_pallas`` tile sweep consumes it.
+    in HBM, reordered to the same tap-major columns, then one
+    ``matmul_pallas`` tile sweep consumes it.
 
 All paths accumulate fp32 over the same 128-sized K chunks in the same
-order, so their outputs are bit-identical.
+order, so their outputs are bit-identical.  The output width is padded to
+the 8-row sublane tile inside the fused kernel and sliced off after.
 """
 from __future__ import annotations
 
@@ -35,14 +41,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.backend import interpret_kernels
 from repro.kernels.matmul.kernel import matmul_pallas
 
 __all__ = ["conv2d_im2col_pallas", "coded_worker_pallas",
            "coded_transition_pallas"]
 
-# Guard for the in-kernel im2col path: one input share (C*hh*wp) and one
-# patch tile (bo*wo x K) must both fit VMEM comfortably.  Geometries past
-# the guard silently take the two-step path (the documented fallback).
+# Guard for the in-kernel im2col path: the input block (tile-padded phase
+# planes) and one patch tile (bo*wo x K) must both fit VMEM comfortably.
+# Geometries past the guard take the streamed variant, else the two-step
+# path (the documented fallback).
 _FUSED_VMEM_ELEMS = 1 << 21  # 2M fp32 elements = 8 MB of the ~16 MB VMEM
 
 
@@ -52,7 +60,7 @@ def conv2d_im2col_pallas(
     stride: int = 1,
     padding: int = 0,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     **tile_kw,
 ) -> jnp.ndarray:
     """``x``: (C, H, W); ``k``: (N, C, KH, KW) -> (N, H', W').
@@ -69,211 +77,201 @@ def conv2d_im2col_pallas(
                                **tile_kw)[0]
 
 
-def _worker_im2col_kernel(x_ref, w_ref, o_ref, *, stride: int, kh: int,
-                          kw: int, bo: int, wo: int, ck: int, bk: int):
-    """One (share, output-row tile, N tile) step of the fused worker GEMM.
+def _space_to_depth(xin: jnp.ndarray, stride: int, hs: int,
+                    ws: int) -> jnp.ndarray:
+    """``(G, C, hh, wp)`` -> channel-last phase planes ``(G, hs, ws, s*s*C)``
+    with ``[g, a, b, (ph*s + pw)*C + c] = x[g, c, a*s + ph, b*s + pw]``
+    (zero past the share's edge).
 
-    ``x_ref``: (1, C, hh, wp) — the whole padded input share, streamed to
-    VMEM by the pallas pipeline.  ``w_ref``: (kp, bn) — one N-tile of the
-    reshaped coded filters, K zero-padded to the chunk grid.  The patch
-    rows for this tile are gathered here, in-kernel, as ``KH*KW`` shifted
-    strided slices of the share — never materialized outside VMEM.
-    """
-    i = pl.program_id(1)
-    x = x_ref[0]  # (C, hh, wp)
-    c, _, wp = x.shape
-    span = (bo - 1) * stride + kh  # input rows feeding bo output rows
-    xwin = jax.lax.dynamic_slice(x, (0, i * bo * stride, 0), (c, span, wp))
-    taps = []
-    for dh in range(kh):
-        for dw in range(kw):
-            taps.append(jax.lax.slice(
-                xwin, (0, dh, dw),
-                (c, dh + (bo - 1) * stride + 1, dw + (wo - 1) * stride + 1),
-                (1, stride, stride),
-            ))  # (C, bo, wo): tap (dh, dw) of every output pixel in the tile
-    # feature order must match kccp-reshaped filters: C slowest, then KH, KW
-    patch = jnp.stack(taps, axis=1).reshape(ck, bo * wo).T  # (bo*wo, ck)
-    kp, bn = w_ref.shape
+    Tap ``(dh, dw) = (qh*s + ph, qw*s + pw)`` of output pixel ``(oh, ow)``
+    then reads plane ``(ph, pw)`` at ``(oh + qh, ow + qw)``: every tap is a
+    unit-stride window, so the kernel needs no strided or dynamic value
+    slices, and channels ride the lane axis."""
+    g, c, hh, wp = xin.shape
+    s = stride
+    x = jnp.pad(xin, ((0, 0), (0, 0), (0, hs * s - hh), (0, ws * s - wp)))
+    x = x.reshape(g, c, hs, s, ws, s).transpose(0, 2, 4, 3, 5, 1)
+    x = x.reshape(g, hs, ws, s * s * c)
+    # trailing dims padded to the (8, 128) VMEM tile: row-window copies of
+    # the planes must slice whole tiles
+    return jnp.pad(x, ((0, 0), (0, 0), (0, _pad_to(ws, 8) - ws),
+                       (0, _ceil128(s * s * c) - s * s * c)))
+
+
+def _fused_geometry(hh: int, wp: int, kh: int, kw: int, stride: int,
+                    ho: int, wo: int) -> tuple[int, int, int]:
+    """``(wo_p, hs, ws)``: the output width padded to the 8-row sublane tile
+    (so ``(bo, wo_p, C) -> (bo*wo_p, C)`` is a layout-preserving reshape)
+    and the phase-plane extent that covers every tap of the padded rows."""
+    wo_p = _pad_to(wo, 8)
+    hs = max(-(-hh // stride), (kh - 1) // stride + ho)
+    ws = max(-(-wp // stride), (kw - 1) // stride + wo_p)
+    return wo_p, hs, ws
+
+
+def _gather_patch(win, lead: tuple, p_ref, r0, *, stride: int, kh: int,
+                  kw: int, c: int, bo: int, wo_p: int):
+    """Gather the ``(bo*wo_p, kp)`` patch tile of ``bo`` output rows into the
+    VMEM scratch ``p_ref``.
+
+    ``win[lead]``: phase planes ``(rows, ws, lanes)``; output row ``r`` of
+    the tile reads plane rows ``r0 + r + qh``.  Patch columns are ordered
+    ``(KH, KW, C)`` — one lane window of ``C`` columns per tap, so every
+    store is a plain 2-D window write."""
+    kp = p_ref.shape[1]
+    ck = kh * kw * c
     if kp > ck:  # zero-pad K to the chunk grid (exact under fp32 addition)
-        patch = jnp.concatenate(
-            [patch, jnp.zeros((bo * wo, kp - ck), patch.dtype)], axis=1)
-    acc = jnp.zeros((bo * wo, bn), jnp.float32)
+        p_ref[:, ck:] = jnp.zeros((bo * wo_p, kp - ck), p_ref.dtype)
+    for dh in range(kh):
+        qh, ph = divmod(dh, stride)
+        for dw in range(kw):
+            qw, pw = divmod(dw, stride)
+            t = dh * kw + dw
+            lane = (ph * stride + pw) * c
+            slab = win[lead + (pl.ds(r0 + qh, bo), pl.ds(qw, wo_p),
+                               slice(None))]
+            p_ref[:, t * c:(t + 1) * c] = slab[:, :, lane:lane + c].reshape(
+                bo * wo_p, c)
+
+
+def _patch_gemm(p_ref, w_ref, o_ref, bk: int):
+    """Sweep the patch tile against one N tile of filters in ``bk`` chunks."""
+    kp, bn = w_ref.shape
+    acc = jnp.zeros((p_ref.shape[0], bn), jnp.float32)
     for kk in range(kp // bk):  # same chunk order as matmul_pallas: bit-compat
         acc += jnp.dot(
-            patch[:, kk * bk:(kk + 1) * bk],
+            p_ref[:, kk * bk:(kk + 1) * bk],
             w_ref[kk * bk:(kk + 1) * bk, :],
             preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
-    o_ref[...] = acc.astype(o_ref.dtype).reshape(1, bo, wo, bn)
+    o_ref[...] = acc.astype(o_ref.dtype).reshape(o_ref.shape)
 
 
-def _k_windows(ck: int, bk: int, kh: int, kw: int, kp: int):
-    """Per-K-chunk channel windows ``(c_lo, cw)`` for the streamed path.
+def _worker_im2col_kernel(x_ref, w_ref, o_ref, p_ref, *, bk: int, **geo):
+    """One (share, output-row tile, N tile) step of the fused worker GEMM.
 
-    Chunk ``kk`` covers patch columns ``[kk*bk, (kk+1)*bk)``; with the
-    (C, KH, KW) feature order those columns touch only channels
-    ``kk*bk // (kh*kw)`` .. ``(last real column) // (kh*kw)`` — the slice
-    of the share the chunk's DMA must bring in.  ``kp = _pad_to(ck, bk)``
-    guarantees every chunk holds at least one real column."""
-    wins = []
-    for kk in range(kp // bk):
-        k0 = kk * bk
-        k1 = min(ck, k0 + bk) - 1  # last real (non-padding) column
-        c_lo = k0 // (kh * kw)
-        c_hi = k1 // (kh * kw)
-        wins.append((c_lo, c_hi - c_lo + 1))
-    return wins
+    ``x_ref``: ``(1, hs, ws, s*s*C)`` — the share's phase planes, streamed
+    to VMEM whole by the pallas pipeline.  ``w_ref``: ``(kp, bn)`` — one
+    N-tile of the reshaped coded filters.  The patch tile is gathered at
+    the first N tile of each (share, row tile) and reused by the rest."""
+    r0 = pl.program_id(1) * geo["bo"]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        _gather_patch(x_ref, (0,), p_ref, r0, **geo)
+
+    _patch_gemm(p_ref, w_ref, o_ref, bk)
 
 
-def _worker_im2col_stream_kernel(x_hbm, w_ref, o_ref, buf, sem, *,
-                                 stride: int, kh: int, kw: int, bo: int,
-                                 wo: int, ck: int, bk: int, windows):
-    """K-streamed variant of ``_worker_im2col_kernel``: the share stays in
-    HBM (``x_hbm``: (G, C, hh, wp), ``memory_space=ANY``) and each K chunk
-    double-buffers only its channel window ``(cw, span, wp)`` into VMEM via
-    async copies — the resident path's whole-share ``(1, C, hh, wp)`` VMEM
-    block never exists.  The per-chunk patch gather and the fp32
-    accumulation order are identical to the resident kernel, so the two
-    variants are bit-identical."""
-    gi = pl.program_id(0)
-    i = pl.program_id(1)
-    span = (bo - 1) * stride + kh
-    r0 = i * bo * stride
-    kp, bn = w_ref.shape
-    n_chunks = kp // bk
+def _worker_im2col_stream_kernel(x_hbm, w_ref, o_ref, p_ref, buf, sem, *,
+                                 span: int, bk: int, **geo):
+    """Streamed variant of ``_worker_im2col_kernel``: the phase planes stay
+    in HBM (``memory_space=HBM``) and each row tile copies in only the
+    ``span`` plane rows its taps read — the whole-share VMEM block never
+    exists.  The patch gather and the fp32 chunk order are the resident
+    kernel's, so the two variants are bit-identical."""
+    gi, r0 = pl.program_id(0), pl.program_id(1) * geo["bo"]
 
-    def copy_in(kk):  # chunk kk's channel window -> VMEM slot kk % 2
-        c_lo, cw = windows[kk]
-        return pltpu.make_async_copy(
-            x_hbm.at[gi, pl.ds(c_lo, cw), pl.ds(r0, span), :],
-            buf.at[kk % 2, pl.ds(0, cw)],
-            sem.at[kk % 2],
-        )
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        copy = pltpu.make_async_copy(x_hbm.at[gi, pl.ds(r0, span)], buf,
+                                     sem.at[0])
+        copy.start()
+        copy.wait()
+        _gather_patch(buf, (), p_ref, 0, **geo)
 
-    copy_in(0).start()
-    if n_chunks > 1:
-        copy_in(1).start()
-    acc = jnp.zeros((bo * wo, bn), jnp.float32)
-    for kk in range(n_chunks):  # static unroll: windows/offsets are static
-        c_lo, cw = windows[kk]
-        copy_in(kk).wait()
-        xw = jax.lax.slice(buf[kk % 2], (0, 0, 0), (cw, span, buf.shape[-1]))
-        taps = []
-        for dh in range(kh):
-            for dw in range(kw):
-                taps.append(jax.lax.slice(
-                    xw, (0, dh, dw),
-                    (cw, dh + (bo - 1) * stride + 1,
-                     dw + (wo - 1) * stride + 1),
-                    (1, stride, stride),
-                ))
-        # window rows are a contiguous block of the full (C, KH, KW) feature
-        # order starting at c_lo*kh*kw — slice the chunk's bk columns out
-        win = jnp.stack(taps, axis=1).reshape(cw * kh * kw, bo * wo).T
-        off = kk * bk - c_lo * kh * kw
-        real = min(ck, (kk + 1) * bk) - kk * bk
-        chunk = jax.lax.slice(win, (0, off), (bo * wo, off + real))
-        if real < bk:  # zero-pad like the resident path (exact in fp32)
-            chunk = jnp.concatenate(
-                [chunk, jnp.zeros((bo * wo, bk - real), chunk.dtype)], axis=1)
-        acc += jnp.dot(
-            chunk,
-            w_ref[kk * bk:(kk + 1) * bk, :],
-            preferred_element_type=jnp.float32,
-        )
-        if kk + 2 < n_chunks:  # prefetch into the slot just consumed
-            copy_in(kk + 2).start()
-    o_ref[...] = acc.astype(o_ref.dtype).reshape(1, bo, wo, bn)
+    _patch_gemm(p_ref, w_ref, o_ref, bk)
 
 
 def _fused_worker_gemm(xin, ke, stride, *, interpret, bo, bn, bk,
                        stream=False):
     """In-kernel-im2col GEMM: xin (G, C, hh, wp) x ke (eb, nb, C, KH, KW)
     -> (G, ho, wo, eb*nb).  ``stream=True`` keeps the share in HBM and
-    double-buffers per-K-chunk channel windows (bit-identical output)."""
+    copies each row tile's plane rows in (bit-identical output)."""
     g, c, hh, wp = xin.shape
     eb, nb, _, kh, kw = ke.shape
     ho = (hh - kh) // stride + 1
     wo = (wp - kw) // stride + 1
     assert ho % bo == 0, f"bo={bo} must divide H'={ho}"
+    wo_p, hs, ws = _fused_geometry(hh, wp, kh, kw, stride, ho, wo)
+    planes = _space_to_depth(xin, stride, hs, ws)
+    _, _, ws, lanes = planes.shape
     ck = c * kh * kw
     n = eb * nb
     bk_ = min(bk, _ceil128(ck))
     kp = _pad_to(ck, bk_)
     bn_ = min(bn, _ceil128(n))
     np_ = _pad_to(n, bn_)
-    w = ke.reshape(n, ck).T  # (ck, N), K ordered (C, KH, KW) like the patch
+    w = _filters_khkwc(ke)  # (ck, N), K ordered (KH, KW, C) like the patch
     if (kp, np_) != (ck, n):
         w = jnp.pad(w, ((0, kp - ck), (0, np_ - n)))
+    dtype = jnp.result_type(xin.dtype, ke.dtype)
+    geo = dict(stride=stride, kh=kh, kw=kw, c=c, bo=bo, wo_p=wo_p, bk=bk_)
+    scratch = [pltpu.VMEM((bo * wo_p, kp), xin.dtype)]
     if stream:
-        windows = tuple(_k_windows(ck, bk_, kh, kw, kp))
-        cw_max = max(cw for _, cw in windows)
-        span = (bo - 1) * stride + kh
-        out = pl.pallas_call(
-            functools.partial(_worker_im2col_stream_kernel, stride=stride,
-                              kh=kh, kw=kw, bo=bo, wo=wo, ck=ck, bk=bk_,
-                              windows=windows),
-            grid=(g, ho // bo, np_ // bn_),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec((kp, bn_), lambda gi, i, j: (0, j)),
-            ],
-            out_specs=pl.BlockSpec((1, bo, wo, bn_),
-                                   lambda gi, i, j: (gi, i, 0, j)),
-            out_shape=jax.ShapeDtypeStruct((g, ho, wo, np_),
-                                           jnp.result_type(xin.dtype,
-                                                           ke.dtype)),
-            scratch_shapes=[
-                pltpu.VMEM((2, cw_max, span, wp), xin.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-            interpret=interpret,
-        )(xin, w)
-        return out if np_ == n else out[..., :n]
+        span = bo + (kh - 1) // stride
+        kernel = functools.partial(_worker_im2col_stream_kernel, span=span,
+                                   **geo)
+        x_spec = pl.BlockSpec(memory_space=pltpu.HBM)
+        scratch += [pltpu.VMEM((span, ws, lanes), xin.dtype),
+                    pltpu.SemaphoreType.DMA((1,))]
+    else:
+        kernel = functools.partial(_worker_im2col_kernel, **geo)
+        x_spec = pl.BlockSpec((1, hs, ws, lanes),
+                              lambda gi, i, j: (gi, 0, 0, 0))
     out = pl.pallas_call(
-        functools.partial(_worker_im2col_kernel, stride=stride, kh=kh, kw=kw,
-                          bo=bo, wo=wo, ck=ck, bk=bk_),
+        kernel,
         grid=(g, ho // bo, np_ // bn_),
-        in_specs=[
-            pl.BlockSpec((1, c, hh, wp), lambda gi, i, j: (gi, 0, 0, 0)),
-            pl.BlockSpec((kp, bn_), lambda gi, i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bo, wo, bn_), lambda gi, i, j: (gi, i, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((g, ho, wo, np_),
-                                       jnp.result_type(xin.dtype, ke.dtype)),
+        in_specs=[x_spec, pl.BlockSpec((kp, bn_), lambda gi, i, j: (0, j))],
+        out_specs=pl.BlockSpec((1, bo, wo_p, bn_),
+                               lambda gi, i, j: (gi, i, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((g, ho, wo_p, np_), dtype),
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(xin, w)
-    return out if np_ == n else out[..., :n]
+    )(planes, w)
+    return out[:, :, :wo, :n]
+
+
+def _filters_khkwc(ke: jnp.ndarray) -> jnp.ndarray:
+    """Coded filter groups ``(eb, nb, C, KH, KW)`` as the ``(KH*KW*C, eb*nb)``
+    GEMM operand, K ordered ``(KH, KW, C)`` — the patch column order of
+    both im2col strategies."""
+    eb, nb, c, kh, kw = ke.shape
+    return ke.transpose(0, 1, 3, 4, 2).reshape(eb * nb, kh * kw * c).T
+
+
+def _fused_vmem(xin_shape, kh: int, kw: int, stride: int, ho: int, wo: int,
+                bo: int, *, stream: bool) -> int | None:
+    """VMEM elements one grid step of the fused kernel holds: the input
+    block (whole share, or a row tile's planes when ``stream``) and the
+    patch tile.  None when ``bo`` does not tile ``H'``."""
+    _, c, hh, wp = xin_shape
+    if ho < 1 or wo < 1 or bo < 1 or ho % bo != 0:
+        return None
+    wo_p, hs, ws = _fused_geometry(hh, wp, kh, kw, stride, ho, wo)
+    rows = bo + (kh - 1) // stride if stream else hs
+    block = rows * _pad_to(ws, 8) * _ceil128(stride * stride * c)
+    patch = bo * wo_p * _ceil128(c * kh * kw)
+    return max(block, patch)
 
 
 def _fused_feasible(xin_shape, kh: int, kw: int, stride: int, ho: int,
                     wo: int, bo: int) -> bool:
-    """Geometry admits the in-kernel im2col path (else: two-step fallback)."""
-    _, c, hh, wp = xin_shape
-    if ho < 1 or wo < 1 or bo < 1 or ho % bo != 0:
-        return False
-    share = c * hh * wp
-    patch = bo * wo * _pad_to(c * kh * kw, 128)
-    return share <= _FUSED_VMEM_ELEMS and patch <= _FUSED_VMEM_ELEMS
+    """Geometry admits the whole-share-resident in-kernel im2col path."""
+    elems = _fused_vmem(xin_shape, kh, kw, stride, ho, wo, bo, stream=False)
+    return elems is not None and elems <= _FUSED_VMEM_ELEMS
 
 
 def _stream_feasible(xin_shape, kh: int, kw: int, stride: int, ho: int,
                      wo: int, bo: int, bk: int) -> bool:
-    """Geometry admits the K-streamed in-kernel im2col path: the double
-    buffer (2 channel windows), the per-chunk patch window, and the whole
-    w N-tile must fit VMEM — but the whole share need not."""
-    _, c, hh, wp = xin_shape
-    if ho < 1 or wo < 1 or bo < 1 or ho % bo != 0:
-        return False
-    ck = c * kh * kw
-    bk_ = min(bk, _ceil128(ck))
-    kp = _pad_to(ck, bk_)
-    cw_max = max(cw for _, cw in _k_windows(ck, bk_, kh, kw, kp))
-    span = (bo - 1) * stride + kh
-    buf = 2 * cw_max * span * wp
-    win = bo * wo * cw_max * kh * kw
-    return (buf <= _FUSED_VMEM_ELEMS and win <= _FUSED_VMEM_ELEMS
+    """Geometry admits the streamed in-kernel im2col path: a row tile's
+    plane rows, the patch tile and the whole w N-tile must fit VMEM — but
+    the whole share need not."""
+    elems = _fused_vmem(xin_shape, kh, kw, stride, ho, wo, bo, stream=True)
+    ck = xin_shape[1] * kh * kw
+    kp = _pad_to(ck, min(bk, _ceil128(ck)))
+    return (elems is not None and elems <= _FUSED_VMEM_ELEMS
             and kp * 128 <= _FUSED_VMEM_ELEMS)
 
 
@@ -293,7 +291,7 @@ def coded_worker_pallas(
     ke: jnp.ndarray,
     stride: int = 1,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     fused_im2col: bool | None = None,
     stream_k: bool | None = None,
     bo: int | None = None,
@@ -319,16 +317,19 @@ def coded_worker_pallas(
 
     ``fused_im2col`` selects the im2col strategy (module docstring); None =
     in-kernel when the geometry admits it.  ``stream_k`` picks the fused
-    path's share residency: True forces the K-streamed variant (share in
-    HBM, per-chunk channel windows double-buffered to VMEM), False forces
+    path's share residency: True forces the streamed variant (planes in
+    HBM, each row tile's plane rows copied to VMEM), False forces
     whole-share-resident, None auto-falls-back to streaming when the share
     is too big for the resident path — so uncoded full-frame convs still
-    take the fused path.  Both variants are bit-identical.  ``bo`` is the
-    fused path's output-row tile (must divide H'; None = ``default_bo``);
+    take the fused path.  Both variants are bit-identical.
+    ``interpret=None`` emulates the kernels only on a CPU backend.
+    ``bo`` is the fused path's output-row tile (must divide H'; None =
+    ``default_bo``);
     ``bm/bn/bk/num_buffers`` tile the GEMM (``bm``/``num_buffers`` drive
     the two-step path's ``matmul_pallas``; the fused path streams shares
     at grid level).
     """
+    interpret = interpret_kernels() if interpret is None else interpret
     batched = xe.ndim == 5
     ea = xe.shape[0]
     b = xe.shape[1] if batched else 1
@@ -363,10 +364,12 @@ def coded_worker_pallas(
             padding=((0, 0), (0, 0)),
             dimension_numbers=("NCHW", "OIHW", "NCHW"),
         )  # (ea*B, C*KH*KW, H', W') — materialized in HBM, then GEMM'd
-        _, ck, ho, wo = patches.shape
-        # M = ea*B*H'*W' output pixels, K = C*KH*KW patch, N = eb*(N/k_b)
-        lhs = patches.transpose(0, 2, 3, 1).reshape(ea * b * ho * wo, ck)
-        rhs = ke.reshape(eb * nb, ck).T
+        ck = c * kh * kw
+        # M = ea*B*H'*W' output pixels, K = KH*KW*C patch (the fused path's
+        # column order), N = eb*(N/k_b)
+        lhs = patches.reshape(ea * b, c, kh * kw, ho, wo).transpose(
+            0, 3, 4, 2, 1).reshape(ea * b * ho * wo, ck)
+        rhs = _filters_khkwc(ke)
         out = matmul_pallas(lhs, rhs, interpret=interpret, bm=bm, bn=bn,
                             bk=bk, num_buffers=num_buffers)  # (M, eb*nb)
         y = out.reshape(ea, b, ho, wo, eb, nb)
@@ -380,7 +383,7 @@ def coded_transition_pallas(
     m_next: jnp.ndarray,
     assemble,
     *,
-    interpret: bool = True,
+    interpret: bool | None = None,
     decode_kw: dict | None = None,
     encode_kw: dict | None = None,
 ) -> jnp.ndarray:
@@ -411,6 +414,7 @@ def coded_transition_pallas(
     """
     from repro.kernels import autotune
 
+    interpret = interpret_kernels() if interpret is None else interpret
     q = d.shape[0]
     rows = outs.reshape(outs.shape[0] * outs.shape[1], -1)
     if decode_kw is None:

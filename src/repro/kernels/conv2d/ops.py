@@ -1,8 +1,7 @@
 """Public conv ops used by CodedConv2d's ``backend='pallas'`` path.
 
-``interpret`` is a real knob here (plumbed from the class APIs down to
-``pl.pallas_call``): ``True`` emulates the kernel on CPU (this container),
-``False`` lowers to Mosaic on real TPU hardware.
+The kernels emulate themselves only where the default backend is the CPU
+(``repro.backend.interpret_kernels``); on a TPU they lower to Mosaic.
 
 When the caller passes no explicit tile/strategy kwargs, the autotune
 ledger (``repro.kernels.autotune``) is consulted at trace time — shapes are
@@ -21,12 +20,11 @@ from .kernel import (
 __all__ = ["conv2d_im2col", "coded_worker", "coded_transition"]
 
 
-def conv2d_im2col(x, k, stride=1, padding=0, *, interpret=True, **tile_kw):
-    return conv2d_im2col_pallas(x, k, stride, padding, interpret=interpret,
-                                **tile_kw)
+def conv2d_im2col(x, k, stride=1, padding=0, **tile_kw):
+    return conv2d_im2col_pallas(x, k, stride, padding, **tile_kw)
 
 
-def coded_worker(xe, ke, stride=1, *, interpret=True, **tile_kw):
+def coded_worker(xe, ke, stride=1, **tile_kw):
     """Fused batched coded-worker subtask: one implicit-GEMM tile sweep.
 
     No explicit ``tile_kw`` -> the autotuned winner for this
@@ -34,15 +32,13 @@ def coded_worker(xe, ke, stride=1, *, interpret=True, **tile_kw):
     """
     if not tile_kw:
         tile_kw = autotune.worker_params(
-            tuple(xe.shape), tuple(ke.shape), stride, interpret=interpret
-        ) or {}
-    return coded_worker_pallas(xe, ke, stride, interpret=interpret, **tile_kw)
+            tuple(xe.shape), tuple(ke.shape), stride) or {}
+    return coded_worker_pallas(xe, ke, stride, **tile_kw)
 
 
-def coded_transition(outs, d, m_next, assemble, *, interpret=True, **kw):
+def coded_transition(outs, d, m_next, assemble, **kw):
     """Fused partition-resident layer transition: decode-GEMM with ReLU
     epilogue -> partition-space pool/halo re-slice -> encode-GEMM.  The two
     GEMM sweeps consult the autotune ledger unless ``decode_kw``/
     ``encode_kw`` are passed."""
-    return coded_transition_pallas(outs, d, m_next, assemble,
-                                   interpret=interpret, **kw)
+    return coded_transition_pallas(outs, d, m_next, assemble, **kw)

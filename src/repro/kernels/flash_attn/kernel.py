@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.backend import interpret_kernels
+
 __all__ = ["flash_attention_pallas"]
 
 NEG_INF = -1e30
@@ -75,9 +77,10 @@ def flash_attention_pallas(
     causal: bool = True,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """q: (BH, Sq, D); k/v: (BH, Sk, D) -> (BH, Sq, D)."""
+    interpret = interpret_kernels() if interpret is None else interpret
     bh, sq, d = q.shape
     sk = k.shape[1]
     scale = scale if scale is not None else d ** -0.5

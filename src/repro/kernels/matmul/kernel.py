@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.backend import interpret_kernels
+
 __all__ = ["matmul_pallas"]
 
 
@@ -39,7 +41,8 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
+        a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
     @pl.when(pl.program_id(2) == k_steps - 1)
@@ -86,7 +89,8 @@ def _matmul_stream_kernel(a_hbm, b_hbm, o_ref, a_buf, b_buf, a_sem, b_sem,
         a_dma(slot, kk).wait()
         b_dma(slot, kk).wait()
         acc_ref[...] += jnp.dot(
-            a_buf[slot], b_buf[slot], preferred_element_type=jnp.float32
+            a_buf[slot], b_buf[slot], preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
         # the compute above released this slot — refill it from k-step
         # kk + num_buffers while the other slots' copies keep the MXU fed
@@ -115,15 +119,15 @@ def matmul_pallas(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
     out_dtype=None,
     relu: bool = False,
     num_buffers: int = 2,
 ) -> jnp.ndarray:
     """``a @ b`` with explicit VMEM tiling.  Shapes padded to block grid.
 
-    ``interpret=True`` runs the kernel body in Python on CPU (this container
-    has no TPU); on real hardware pass ``interpret=False``.
+    ``interpret=None`` emulates the kernel where the default backend is the
+    CPU and lowers it to Mosaic anywhere else (``repro.backend``).
 
     ``relu=True`` fuses ``max(., 0)`` into the flush epilogue — the output
     tile is rectified in-register on the last K step, so a GEMM-then-ReLU
@@ -143,6 +147,7 @@ def matmul_pallas(
     if num_buffers < 1:
         raise ValueError(f"num_buffers must be >= 1, got {num_buffers}")
     out_dtype = out_dtype or jnp.result_type(a.dtype, b.dtype)
+    interpret = interpret_kernels() if interpret is None else interpret
 
     bm_, bn_, bk_ = (min(bm, _ceil8(m)), min(bn, _ceil128(n)), min(bk, _ceil128(k)))
     mp, np_, kp = _pad_to(m, bm_), _pad_to(n, bn_), _pad_to(k, bk_)
@@ -173,8 +178,8 @@ def matmul_pallas(
             ),
             grid=(mp // bm_, np_ // bn_),
             in_specs=[
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((bm_, bn_), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),

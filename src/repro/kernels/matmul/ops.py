@@ -6,9 +6,7 @@ from .kernel import matmul_pallas
 __all__ = ["matmul"]
 
 
-def matmul(a, b, *, interpret=True, **kw):
+def matmul(a, b, **kw):
     if not kw:  # no explicit tiles: consult the autotune ledger (trace-time)
-        kw = autotune.matmul_params(
-            a.shape[0], a.shape[1], b.shape[1], interpret=interpret
-        ) or {}
-    return matmul_pallas(a, b, interpret=interpret, **kw)
+        kw = autotune.matmul_params(a.shape[0], a.shape[1], b.shape[1]) or {}
+    return matmul_pallas(a, b, **kw)

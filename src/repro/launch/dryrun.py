@@ -29,7 +29,6 @@ import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
-from repro import compat
 from repro.configs import ARCH_IDS, get_bundle  # noqa: E402
 from repro.configs.shapes import SHAPES, batch_structs  # noqa: E402
 from repro.launch import steps as steps_mod  # noqa: E402
@@ -70,7 +69,7 @@ def lower_cell(arch: str, shape: str, mesh, *, smoke_scale=None, extra=None):
     batch, cache = batch_structs(bundle, shape, smoke_scale=smoke_scale)
     params = bundle.param_shapes(jnp.bfloat16)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             from repro.models.common import count_params
 
@@ -159,7 +158,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, force=False, smoke_scale
                 arch, shape, mesh, smoke_scale=smoke_scale
             )
             mem = compiled.memory_analysis()
-            cost = compat.cost_analysis(compiled)
+            cost = compiled.cost_analysis()
             hlo_cost = analyze_hlo(compiled.as_text(), n_dev)
             rec.update(
                 status="ok",

@@ -12,20 +12,18 @@ from __future__ import annotations
 
 import jax
 
-from repro import compat
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(
+    return jax.make_mesh(
         shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
     )
 
 
 def make_host_mesh():
     """1-device mesh for tests/examples on this CPU container."""
-    return compat.make_mesh(
+    return jax.make_mesh(
         (1, 1), ("data", "model"),
         axis_types=(jax.sharding.AxisType.Auto,) * 2,
     )
@@ -46,7 +44,7 @@ def make_worker_mesh(n: int, devices=None):
         raise ValueError(f"need n >= 1 workers, got {n}")
     devs = list(devices) if devices is not None else list(jax.devices())
     devs = devs[:n]
-    return compat.make_mesh(
+    return jax.make_mesh(
         (len(devs),), ("workers",),
         axis_types=(jax.sharding.AxisType.Auto,), devices=devs,
     )

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
+from repro.backend import enable_compile_cache
 from repro.configs import get_bundle
 from repro.launch.mesh import make_host_mesh
 
@@ -37,7 +37,7 @@ def serve_lm(arch: str, *, batch: int, prompt_len: int, gen: int, smoke: bool,
     mesh = mesh or make_host_mesh()
     max_len = prompt_len + gen
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = bundle.init(jax.random.PRNGKey(0), param_dtype)
         prompts = jax.random.randint(
             jax.random.PRNGKey(1), (batch, prompt_len), 0, bundle.cfg.vocab
@@ -255,6 +255,7 @@ def main():
                          "in flight at once (1 = serial dispatch->collect; "
                          "CNN only)")
     args = ap.parse_args()
+    enable_compile_cache()
     archs = args.arch or ["qwen3-4b"]
     if all(a in CNN_SPECS for a in archs):
         serve_cnn(archs, requests=args.requests, workers=args.workers,

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
-from repro import compat
 from repro.checkpoint import AsyncCheckpointer, latest_step, restore
 from repro.configs import get_bundle
 from repro.data import DataConfig, SyntheticTokens
@@ -39,7 +38,7 @@ def train(arch: str, *, steps: int, batch: int, seq: int, smoke: bool,
     )
     step_fn, param_ps, opt_ps = steps_mod.build_train_step(bundle, mesh, tcfg)
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params = bundle.init(jax.random.PRNGKey(0), param_dtype)
         opt_state = init_state(params)
         start = 0

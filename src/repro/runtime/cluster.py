@@ -129,15 +129,13 @@ class FcdccCluster:
 
     def __init__(self, plan: FcdccPlan, straggler: StragglerModel | None = None,
                  mode: str = "threads", backend: str = "lax",
-                 interpret: bool = True, pool: str | None = None,
+                 pool: str | None = None,
                  devices=None):
         assert mode in ("threads", "simulated")
         self.plan = plan
         self.straggler = straggler or StragglerModel.none(plan.n)
         self.mode = mode
         self.backend = backend
-        # pallas-only: True emulates worker kernels on CPU, False -> real TPU
-        self.interpret = interpret
         # worker pool selection (see devicepool.resolve_pool): None picks
         # the device pool whenever real parallelism is available
         self.pool = resolve_pool(pool, mode, devices)
@@ -231,7 +229,7 @@ class FcdccCluster:
             layer = self._coded_layers.get(key)
             if layer is None:
                 layer = self._coded_layers[key] = CodedConv2d(
-                    plan, geo, backend=self.backend, interpret=self.interpret
+                    plan, geo, backend=self.backend
                 )
             return layer
 

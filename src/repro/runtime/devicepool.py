@@ -39,7 +39,11 @@ core; pass an explicit ``poll_interval_s`` for a fixed period (tests).
 
 Both pools share the ``PendingBatch`` in-flight handle and the
 inf = dead / nan = discarded / finite = measured ``worker_times``
-convention, so ``LayerTiming`` semantics are pool-independent.
+convention, so ``LayerTiming`` semantics are pool-independent.  Only an
+*injected* dead worker (``InjectedWorkerFailure``) is tolerated as a lost
+subtask; any other exception a worker raises — a kernel the compiler
+refuses, device memory exhausted, a device fault — is a real fault and
+propagates out of ``collect`` to the round's caller.
 """
 from __future__ import annotations
 
@@ -52,13 +56,19 @@ import jax
 import numpy as np
 
 __all__ = [
-    "ClusterDegraded", "DeviceWorkerPool", "PendingBatch", "StragglerModel",
-    "ThreadWorkerPool", "make_pool", "resolve_pool",
+    "ClusterDegraded", "DeviceWorkerPool", "InjectedWorkerFailure",
+    "PendingBatch", "StragglerModel", "ThreadWorkerPool", "make_pool",
+    "resolve_pool",
 ]
 
 
 class ClusterDegraded(RuntimeError):
     pass
+
+
+class InjectedWorkerFailure(Exception):
+    """A worker the ``StragglerModel`` declares dead (``delay = inf``) — the
+    one failure a round tolerates by decoding from the survivors."""
 
 
 @dataclasses.dataclass
@@ -95,7 +105,8 @@ class PendingBatch:
     timer-deferred stragglers dispatch).  ``worker_times`` is live — workers
     write into it as they finish — so ``collect`` snapshots it before
     returning.  ``expected`` (device pool) is the set of live workers whose
-    result will eventually appear."""
+    result will eventually appear; ``errors`` (device pool) holds what a
+    dispatch raised, for ``collect`` to re-raise."""
 
     futures: dict
     results: dict  # guarded-by: self.lock
@@ -103,6 +114,7 @@ class PendingBatch:
     t_start: float
     expected: set | None = None
     lock: threading.Lock | None = None
+    errors: list = dataclasses.field(default_factory=list)  # guarded-by: self.lock
 
 
 def resolve_pool(pool: str | None, mode: str, devices=None) -> str:
@@ -209,7 +221,7 @@ class ThreadWorkerPool:
 
         def work(i):
             if not np.isfinite(delays[i]):
-                raise RuntimeError(f"worker {i} failed")
+                raise InjectedWorkerFailure(f"worker {i} failed")
             t = time.perf_counter()
             out = jax.block_until_ready(fn(i)(xe[i], _ke_of(ke, i)))
             dt = time.perf_counter() - t
@@ -233,15 +245,19 @@ class ThreadWorkerPool:
 
     def ready(self, pending: PendingBatch, delta: int) -> bool:
         """Non-blocking: would ``collect`` return without waiting?  True
-        once delta subtasks finished cleanly — or once *every* future is
-        done (possibly with failures), so a degraded round reports ready
-        and lets ``collect`` raise ``ClusterDegraded`` instead of the
-        engine polling it forever."""
+        once delta subtasks finished cleanly, once a subtask hit a real
+        fault (``collect`` raises it), or once *every* future is done, so
+        a degraded round reports ready and lets ``collect`` raise
+        ``ClusterDegraded`` instead of the engine polling it forever."""
         if self.mode != "threads":
             return True  # simulated: results were computed at submit time
         done = [f for f in pending.futures.values() if f.done()]
-        ok = sum(1 for f in done if f.exception() is None)
-        return ok >= delta or len(done) == len(pending.futures)
+        errs = [f.exception() for f in done]
+        ok = sum(1 for e in errs if e is None)
+        faulted = any(e is not None
+                      and not isinstance(e, InjectedWorkerFailure)
+                      for e in errs)
+        return ok >= delta or faulted or len(done) == len(pending.futures)
 
     def collect(self, pending: PendingBatch, delta: int):
         results = dict(pending.results)
@@ -253,9 +269,13 @@ class ThreadWorkerPool:
                 for f in done:
                     try:
                         i, out = f.result()
-                        results[i] = out
-                    except RuntimeError:
-                        pass
+                    except InjectedWorkerFailure:
+                        continue
+                    except Exception:
+                        for g in outstanding:  # abandon the rest, re-raise
+                            g.cancel()
+                        raise
+                    results[i] = out
             t_compute = time.perf_counter() - pending.t_start
             for f in outstanding:  # abandon stragglers, don't join them
                 f.cancel()
@@ -393,8 +413,17 @@ class DeviceWorkerPool:
                                expected=set(), lock=lock)
 
         def dispatch(i):
-            # async: enqueues on device i's queue and returns immediately
-            out = fn(i)(jax.device_put(xe[i], self.devices[i]), _ke_of(ke, i))
+            # async: enqueues on device i's queue and returns immediately;
+            # a refused compile or placement is kept for collect to raise
+            # (a deferred dispatch runs on a timer thread, where it would
+            # otherwise vanish and leave collect waiting forever)
+            try:
+                out = fn(i)(jax.device_put(xe[i], self.devices[i]),
+                            _ke_of(ke, i))
+            except Exception as err:
+                with pending.lock:
+                    pending.errors.append(err)
+                return
             with lock:
                 results[i] = out
 
@@ -430,6 +459,8 @@ class DeviceWorkerPool:
         need = min(delta, len(pending.expected))
         with pending.lock:
             avail = list(pending.results.values())
+            if pending.errors:
+                return True
         return sum(1 for a in avail if a.is_ready()) >= need
 
     def collect(self, pending: PendingBatch, delta: int):
@@ -444,6 +475,8 @@ class DeviceWorkerPool:
         sleep_s = self._POLL_MIN
         while len(reaped) < need:
             with pending.lock:
+                if pending.errors:
+                    raise pending.errors[0]
                 avail = {i: a for i, a in pending.results.items()
                          if i not in reaped}
             progressed = False

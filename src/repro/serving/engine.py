@@ -163,7 +163,7 @@ class CodedServer:
                  q: int | None = None, default_kab=None, input_hw=None,
                  straggler: StragglerModel | None = None,
                  mode: str = "simulated", execution: str = "cluster",
-                 backend: str = "lax", interpret: bool = True,
+                 backend: str = "lax",
                  bucket_sizes=None, max_inflight: int = 2,
                  pipeline_depth: int = 2,
                  model: str | None = None,
@@ -175,14 +175,13 @@ class CodedServer:
         models afterwards with ``register_model``.
 
         ``backend="pallas"`` serves every bucketed batch program through the
-        fused coded-worker Pallas kernel; ``interpret=False`` lowers those
-        kernels to real TPU hardware instead of CPU emulation.
+        fused coded-worker Pallas kernel (emulated only on a CPU backend).
         ``fuse_transitions=True`` serves on the partition-resident path:
         batches advance between ConvL boundaries as coded partition shares,
         never materializing the full activation between layers."""
         pipeline = build_cnn_pipeline(
             name, params, n, q=q, default_kab=default_kab, input_hw=input_hw,
-            backend=backend, interpret=interpret,
+            backend=backend,
             bucket_sizes=(bucket_sizes if bucket_sizes is not None
                           else DEFAULT_BUCKETS),
             fuse_transitions=fuse_transitions,
@@ -199,7 +198,7 @@ class CodedServer:
         """Load ``pipeline`` as model ``name`` onto the shared worker pool.
 
         The first registration creates the cluster (inheriting the
-        pipeline's backend/interpret); later ones must target the same
+        pipeline's backend); later ones must target the same
         worker count and backend.  Each model gets its own scheduler
         (queue, buckets, in-flight capacity) — registration happens before
         ``start()``.  The pipeline registry itself is the cluster's
@@ -223,13 +222,11 @@ class CodedServer:
                     f"model {name!r} targets n={pipeline.n}, shared pool "
                     f"has n={self.cluster.n}"
                 )
-            if (pipeline.backend, pipeline.interpret) != \
-                    (self.cluster.backend, self.cluster.interpret):
+            if pipeline.backend != self.cluster.backend:
                 raise ValueError(
                     f"model {name!r} built for backend="
-                    f"{pipeline.backend!r}/interpret={pipeline.interpret}, "
-                    f"shared pool runs {self.cluster.backend!r}/"
-                    f"interpret={self.cluster.interpret}"
+                    f"{pipeline.backend!r}, shared pool runs "
+                    f"{self.cluster.backend!r}"
                 )
         buckets = bucket_sizes if bucket_sizes is not None \
             else self._default_buckets
@@ -246,12 +243,12 @@ class CodedServer:
             )
         if self.cluster is None:
             # the cluster runs each pipeline's own worker programs, so it
-            # must share the pipelines' backend (lax / pallas) and
-            # interpret knob; the worker pool comes from the server's
+            # must share the pipelines' backend (lax / pallas); the
+            # worker pool comes from the server's
             # explicit preference, else the pipeline's
             self.cluster = FcdccCluster(
                 pipeline.specs[0].plan, self._straggler, mode=self.mode,
-                backend=pipeline.backend, interpret=pipeline.interpret,
+                backend=pipeline.backend,
                 pool=self._pool if self._pool is not None else pipeline.pool,
                 devices=(self._devices if self._devices is not None
                          else pipeline.devices),

@@ -140,7 +140,7 @@ class CodedLMServer:
             if self.cluster is None:
                 self.cluster = FcdccCluster(
                     pipeline.specs[0].plan, straggler, mode=mode,
-                    backend=pipeline.backend, interpret=pipeline.interpret,
+                    backend=pipeline.backend,
                     pool=pool if pool is not None else pipeline.pool,
                     devices=devices if devices is not None
                     else pipeline.devices,

@@ -14,7 +14,6 @@ import math
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 # canonical logical axes
 BATCH = ("pod", "data")  # batch (or sequence for long-context) shards here
@@ -75,7 +74,7 @@ def shard_hint(x, *axes):
     Each entry of ``axes`` is None, an axis name, or a tuple of candidate
     axis names to use jointly (e.g. ``BATCH`` = ("pod", "data")).
     """
-    am = compat.get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     if am.empty:
         return x
     spec = resolve_pspec(x.shape, axes, dict(am.shape))

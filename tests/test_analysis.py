@@ -137,9 +137,7 @@ def test_baked_const_allowed_shape_and_small_consts_pass():
 
 
 def test_f64_positive():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64():
         cell = _cell(
             lambda x: x.astype(jnp.float64).sum(),
             [jax.ShapeDtypeStruct((4,), jnp.float32)],

@@ -167,7 +167,7 @@ def test_cluster_straggler_skipped(smoke, backend):
     pipe = _pipe(smoke, backend=backend)
     st = StragglerModel(np.array([0.0, 0.0, 0.05, 0.0]))  # worker 2 straggles
     cluster = FcdccCluster(pipe.specs[0].plan, st, mode="simulated",
-                           backend=backend, interpret=True)
+                           backend=backend)
     try:
         cluster.load_pipeline(pipe, "lm")
         prompts = [PROMPT, PROMPT2]
@@ -192,7 +192,7 @@ def test_cluster_dead_worker(smoke):
     st = StragglerModel(np.array([0.0, float("inf"), 0.0, 0.0]))  # worker 1 dead
     pipe = _pipe(smoke)
     cluster = FcdccCluster(pipe.specs[0].plan, st, mode="simulated",
-                           backend="lax", interpret=True)
+                           backend="lax")
     try:
         cluster.load_pipeline(pipe, "lm")
         prompts = [PROMPT]
@@ -237,7 +237,7 @@ def test_device_pool_decode(smoke, backend):
     for pool in ("threads", "device"):
         p = _pipe(smoke, backend=backend)
         cluster = FcdccCluster(p.specs[0].plan, st, mode="threads",
-                               backend=backend, interpret=True, pool=pool)
+                               backend=backend, pool=pool)
         try:
             cluster.load_pipeline(p, "lm")
             cache, nxt, pos = _prefilled(p, cfg, params, prompts)

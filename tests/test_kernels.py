@@ -177,25 +177,33 @@ def test_worker_stream_k_auto_fallback(monkeypatch):
     ho = wo = hh - kh + 1
     bo = K.default_bo(ho, wo)
     ref = K.coded_worker_pallas(xe, ke, 1, fused_im2col=True, stream_k=False)
-    monkeypatch.setattr(K, "_FUSED_VMEM_ELEMS", 90_000)  # share = 102400
+    monkeypatch.setattr(K, "_FUSED_VMEM_ELEMS", 90_000)  # share = 215040
     assert not K._fused_feasible((1, c, hh, wp), kh, kh, 1, ho, wo, bo)
     assert K._stream_feasible((1, c, hh, wp), kh, kh, 1, ho, wo, bo, 128)
     auto = K.coded_worker_pallas(xe, ke, 1)  # picks the streamed fused path
     assert np.array_equal(np.asarray(ref), np.asarray(auto))
 
 
-def test_stream_k_channel_windows():
-    """Window algebra: every chunk's channel window covers exactly its real
-    columns, and windows stay small relative to C for multi-tap kernels."""
-    from repro.kernels.conv2d.kernel import _k_windows, _pad_to
+@pytest.mark.parametrize("stride", [1, 2, 4])
+def test_space_to_depth_phase_planes(stride):
+    """Phase-plane layout the fused kernel reads: element
+    ``[g, a, b, (ph*s + pw)*C + c]`` is ``x[g, c, a*s + ph, b*s + pw]``,
+    zero past the share's edge and in the (8, 128) tile padding."""
+    from repro.kernels.conv2d.kernel import _space_to_depth
 
-    ck, bk, kh, kw = 64 * 9, 128, 3, 3
-    wins = _k_windows(ck, bk, kh, kw, _pad_to(ck, bk))
-    for kk, (c_lo, cw) in enumerate(wins):
-        k0, k1 = kk * bk, min(ck, (kk + 1) * bk) - 1
-        assert c_lo == k0 // (kh * kw)
-        assert c_lo + cw - 1 == k1 // (kh * kw)
-    assert max(cw for _, cw in wins) <= -(-bk // (kh * kw)) + 1
+    g, c, hh, wp = 2, 3, 11, 13
+    hs, ws = -(-hh // stride) + 1, -(-wp // stride) + 2
+    x = RNG.standard_normal((g, c, hh, wp)).astype(np.float32)
+    planes = np.asarray(_space_to_depth(jnp.asarray(x), stride, hs, ws))
+    assert planes.shape[1] == hs
+    assert planes.shape[2] % 8 == 0 and planes.shape[3] % 128 == 0
+    want = np.zeros_like(planes)
+    for ph in range(stride):
+        for pw in range(stride):
+            sub = x[:, :, ph::stride, pw::stride].transpose(0, 2, 3, 1)
+            lo = (ph * stride + pw) * c
+            want[:, :sub.shape[1], :sub.shape[2], lo:lo + c] = sub
+    np.testing.assert_array_equal(planes, want)
 
 
 @settings(max_examples=15, deadline=None)

@@ -154,7 +154,7 @@ def test_cnn_lm_co_serving_one_pool(smoke, refs):
     )
     lm_pipe = _pipe(smoke)
     cluster = FcdccCluster(cnn_pipe.specs[0].plan, None, mode="simulated",
-                           backend="lax", interpret=True)
+                           backend="lax")
     try:
         cluster.load_pipeline(cnn_pipe, "cnn")
         rng = np.random.default_rng(0)

@@ -16,6 +16,7 @@ import time
 import urllib.error
 import urllib.request
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -292,6 +293,48 @@ def test_server_degraded_cluster_fails_requests_not_engine():
         # the engine survived the failed batch and still rejects bad shapes
         with pytest.raises(ValueError, match="request shape"):
             server.submit(jnp.zeros((3, 5, 5)))
+
+
+@pytest.mark.parametrize("fault", ["device", "injected"])
+def test_server_surfaces_worker_faults_tolerates_dead_workers(fault):
+    """Under the thread pool, a worker program that raises a
+    ``JaxRuntimeError`` (a device fault, a kernel the compiler refuses)
+    fails the request with that error instead of passing for a dead
+    worker; an injected dead worker (``delay = inf``) is still decoded
+    around."""
+    pipe, _ = _pipeline()
+    ref_pipe, _ = _pipeline()
+    delays = np.zeros(6)
+    armed = threading.Event()
+    if fault == "injected":
+        delays[3] = np.inf
+    else:
+        # the worker program both layers share (one program key): a device
+        # run that fails once armed, as an XLA runtime error would
+        compiled = jax.jit(pipe.layers[0].worker_compute)
+
+        def program(xe_i, ke_i):
+            if armed.is_set():
+                raise jax.errors.JaxRuntimeError(
+                    "INTERNAL: injected device fault")
+            return compiled(xe_i, ke_i)
+
+        pipe._cluster_programs[pipe.specs[0].program_key] = program
+    server = CodedServer(pipe, StragglerModel(delays), mode="threads",
+                         pool="threads")
+    server.warmup()
+    armed.set()
+    x = _images(1)[0]
+    with server:
+        h = server.submit(x)
+        if fault == "device":
+            with pytest.raises(jax.errors.JaxRuntimeError,
+                               match="injected device fault"):
+                h.result(timeout=60.0)
+        else:
+            np.testing.assert_allclose(
+                np.asarray(h.result(timeout=60.0)),
+                np.asarray(ref_pipe.run(x)), rtol=1e-4, atol=1e-4)
 
 
 def test_server_shutdown_without_drain_cancels():
