@@ -1,0 +1,76 @@
+"""BENCHMARK.json resolves to its files and keeps to the contract's form."""
+import json
+import os
+import re
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, os.path.join(ROOT, "bench"))
+
+from harness import spec  # noqa: E402
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+BENCH = spec.load_benchmark(ROOT)
+WORKLOADS = [w["name"] for w in BENCH["workloads"]]
+
+
+def test_bench_top_level_keys_and_sizes():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
+    assert 1 <= BENCH["run_seconds"] <= 51
+    cells = len(BENCH["workloads"])
+    # a full check of 24 cells fits 43200 s
+    assert 2 + 14 * 24 <= 43200 and \
+        (2 + 14 * 24) * (BENCH["run_seconds"] + 60) + 24 * 180 + 1200 \
+        <= 43200
+    assert 1 <= cells <= 24
+    assert sum(w["chips"] == 4 for w in BENCH["workloads"]) <= max(
+        1, cells // 2)
+    for p in BENCH["paths"]:
+        assert os.path.isdir(os.path.join(ROOT, p))
+    assert BENCH["command"][1].startswith(BENCH["paths"][0] + "/")
+
+
+@pytest.mark.parametrize("workload", WORKLOADS)
+def test_bench_workload_resolves_to_its_files(workload):
+    cell = spec.resolve(workload, ROOT)
+    assert cell.config["name"] == next(
+        w["config"] for w in BENCH["workloads"] if w["name"] == workload)
+    assert cell.traffic["loop"] in ("open", "closed")
+    names = {m["name"] for m in cell.end_to_end}
+    assert "setup_s" in names and len(names) >= 2
+    assert cell.per_layer
+    for m in cell.per_layer:
+        assert callable(spec.metric_reader(m["name"], ROOT))
+        assert m["moves"] in names
+
+
+def test_bench_names_units_and_bounds():
+    metrics = BENCH["end_to_end"] + BENCH["per_layer"]
+    for entry in BENCH["configs"] + BENCH["workloads"] + metrics:
+        assert NAME.match(entry["name"]), entry["name"]
+    assert len({m["name"] for m in metrics}) == len(metrics)
+    for m in metrics:
+        assert UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
+        for w in m.get("workloads", []):
+            assert w in WORKLOADS
+    for m in BENCH["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    layers = {}
+    for m in BENCH["per_layer"]:
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+        layers.setdefault(m["layer"], []).append(m["name"])
+    for c in BENCH["configs"]:
+        path = os.path.join(ROOT, c["file"])
+        assert c["file"].startswith("bench/") and os.path.isfile(path)
+        with open(path) as f:
+            cfg = json.load(f)
+        assert cfg["reduced"] == c["reduced"]
+        assert cfg["name"] == c["name"]
