@@ -116,7 +116,6 @@ class CodedConv2d:
         self.a_code, self.b_code = plan.codes
         # instrumentation: CodedPipeline/tests assert encode-once semantics
         self.filter_encode_calls = 0
-        self.input_encode_calls = 0
 
     # -- master side: encode ---------------------------------------------
     @full_f32
@@ -127,7 +126,6 @@ class CodedConv2d:
         subset (``(k_a, ell_a*m)``, possibly a traced array) to encode only
         m selected workers' shares instead of all n.
         """
-        self.input_encode_calls += 1
         parts = apcp_partition(x, self.geo)
         coded = encode_tensor_list(
             parts, self.a_code.matrix if matrix is None else matrix
@@ -144,7 +142,6 @@ class CodedConv2d:
         ``apcp_partition`` step of ``encode_inputs`` is skipped.  ``matrix``
         as in ``encode_inputs``.
         """
-        self.input_encode_calls += 1
         assert parts.shape[0] == self.plan.k_a, (parts.shape, self.plan)
         coded = encode_tensor_list(
             parts, self.a_code.matrix if matrix is None else matrix

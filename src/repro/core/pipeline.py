@@ -37,6 +37,7 @@ from .crme import recovery_matrix
 from .fcdcc import CodedConv2d, FcdccPlan
 from .nsctc import encode_tensor_list, group_by_worker
 from .partition import ConvGeometry, merge_output, partition_transition
+from .programs import named
 
 __all__ = [
     "CodedLayerSpec",
@@ -254,7 +255,6 @@ class CodedPipeline:
             layer.encode_filters(jnp.asarray(params[s.name]))
             for s, layer in zip(specs, self.layers)
         ]
-        self.input_encode_calls = 0
         # program caches -------------------------------------------------
         self._encoders: dict[int, callable] = {}
         self._cluster_programs: dict[tuple, callable] = {}  # per-worker call
@@ -395,11 +395,11 @@ class CodedPipeline:
     # -- program caches ----------------------------------------------------
     def encoder(self, idx: int):
         """Jitted APCP+encode program for layer ``idx`` (the layer's own
-        ``encode_inputs``; its call counter only ticks at trace time — the
-        pipeline counts real invocations in ``input_encode_calls``)."""
+        ``encode_inputs``)."""
         fn = self._encoders.get(idx)
         if fn is None:
-            fn = self._encoders[idx] = jax.jit(self.layers[idx].encode_inputs)
+            fn = self._encoders[idx] = jax.jit(
+                named(self.layers[idx].encode_inputs, "encode"))
         return fn
 
     def worker_program(self, idx: int, *, over_workers: bool = True):
@@ -480,7 +480,7 @@ class CodedPipeline:
                 blocks = true_rows.reshape((_q,) + outs.shape[2:])
                 return relu_pool(merge_output(blocks, _geo), _pool)
 
-            fn = self._decoders[idx] = jax.jit(dec)
+            fn = self._decoders[idx] = jax.jit(named(dec, "decode"))
         return fn
 
     def decoder(self, idx: int, worker_ids: tuple[int, ...]):
@@ -542,7 +542,7 @@ class CodedPipeline:
                     return group_by_worker(coded, ell_next)
 
             fn = self._transitions[key] = jax.jit(
-                full_f32(trans),
+                named(full_f32(trans), "transition"),
                 donate_argnums=(0,) if self.donate_transitions else (),
             )
         return fn
@@ -775,7 +775,6 @@ class CodedPipeline:
             x = x[None]
         for idx, layer in enumerate(self.layers):
             ids = self.layer_worker_ids(idx, worker_ids)
-            self.input_encode_calls += 1
             # encode only the selected workers' shares (matrix is a runtime
             # argument, so any subset reuses the one per-layer program)
             m_sel = jnp.asarray(self.encode_columns(idx, ids))
@@ -837,7 +836,6 @@ class CodedPipeline:
             # transition of layer i re-encodes directly for layer i+1's
             # selected workers; only the final layer merges to a tensor.
             last = len(self.specs) - 1
-            self.input_encode_calls += 1
             xe = self.encoder(0)(x, prepared[0][0])
             for idx, (m_sel, sel, d) in enumerate(prepared):
                 outs = self.worker_program(idx)(
@@ -849,7 +847,6 @@ class CodedPipeline:
                     x = self.decoder_fn(idx)(outs, d)
             return x[0] if squeeze else x
         for idx, (m_sel, sel, d) in enumerate(prepared):
-            self.input_encode_calls += 1
             xe = self.encoder(idx)(x, m_sel)
             outs = self.worker_program(idx)(xe, self.coded_filters[idx][sel])
             x = self.decoder_fn(idx)(outs, d)

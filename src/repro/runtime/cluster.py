@@ -55,8 +55,8 @@ Entry points:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
-import time
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +75,7 @@ from .devicepool import (  # re-exported for back-compat  # noqa: F401
     make_pool,
     resolve_pool,
 )
+from .spans import DECODE, ENCODE, GATHER, INVERSE, SUBMIT, TRANSITION, timed
 
 
 @dataclasses.dataclass
@@ -87,6 +88,18 @@ class LayerTiming:
     worker_compute_s: list
     used_workers: list
     name: str = ""
+    # the round's subtasks: how many reached the device, were cancelled
+    # before starting, and were decoded from (delta), and seconds from
+    # submit to the delta-th finish; ``prep_s`` is the workers' share
+    # preparation since the previous collected round (a straggler's lands
+    # in a later round, so each started subtask counts once)
+    prep_s: float = 0.0
+    started: int = 0
+    cancelled: int = 0
+    used: int = 0
+    delta_ready_s: float = 0.0
+    # master-side span seconds of the round, by span name
+    phases: dict = dataclasses.field(default_factory=dict)
 
     @property
     def total_s(self):
@@ -115,6 +128,9 @@ class PendingRound:
     pending: PendingBatch
     t_encode: float
     fused_mid: bool  # fused pipeline, non-final layer: transition, no decode
+    round: int = -1  # the cluster's round id, carried by every span
+    bucket: int = 0
+    phases: dict = dataclasses.field(default_factory=dict)  # span seconds
 
 
 class FcdccCluster:
@@ -162,6 +178,8 @@ class FcdccCluster:
         # worker-program signatures already run once (compile happened
         # outside a timed collect); keyed by (program key, operand shapes)
         self._warmed: set[tuple] = set()  # guarded-by: self._registry_lock
+        # ids of dispatched pipeline rounds, for their spans
+        self._round_ids = itertools.count()
 
     @property
     def n(self) -> int:
@@ -414,29 +432,29 @@ class FcdccCluster:
         layer = self.coded_layer(geo, plan)
         n, delta = plan.n, plan.delta
 
-        t0 = time.perf_counter()
-        xe = jax.block_until_ready(layer.encode_inputs(x))
-        ke = coded_filters
-        code_key = self._filter_code_key(plan, geo)
-        if ke is None and layer_name is not None:
-            # resident hit only under the same filter-code key AND when the
-            # caller passed no weights or the *same* weights object the cache
-            # was built from — a plan change or new weights under an old name
-            # re-encode rather than silently decoding against filters coded
-            # with the wrong matrices
-            ent = self._resident.get(layer_name)
-            if ent is not None and ent[0] == code_key and (
-                k is None or ent[2] is k
-            ):
-                ke = ent[1]
-        if ke is None:
-            if k is None:
-                raise ValueError("need k, coded_filters, or resident layer_name")
-            ke = jax.block_until_ready(layer.encode_filters(k))
-            if layer_name is not None:
-                with self._registry_lock:
-                    self._resident[layer_name] = (code_key, ke, k)
-        t_encode = time.perf_counter() - t0
+        with timed(ENCODE) as enc:
+            xe = jax.block_until_ready(layer.encode_inputs(x))
+            ke = coded_filters
+            code_key = self._filter_code_key(plan, geo)
+            if ke is None and layer_name is not None:
+                # resident hit only under the same filter-code key AND when
+                # the caller passed no weights or the *same* weights object
+                # the cache was built from — a plan change or new weights
+                # under an old name re-encode rather than silently decoding
+                # against filters coded with the wrong matrices
+                ent = self._resident.get(layer_name)
+                if ent is not None and ent[0] == code_key and (
+                    k is None or ent[2] is k
+                ):
+                    ke = ent[1]
+            if ke is None:
+                if k is None:
+                    raise ValueError(
+                        "need k, coded_filters, or resident layer_name")
+                ke = jax.block_until_ready(layer.encode_filters(k))
+                if layer_name is not None:
+                    with self._registry_lock:
+                        self._resident[layer_name] = (code_key, ke, k)
 
         impl = self._pool_impl()
         pkey = (layer.plan.ell_a, layer.plan.ell_b, layer.geo.stride)
@@ -458,11 +476,11 @@ class FcdccCluster:
         results, worker_times, t_compute = self.collect(pending, delta)
 
         ids, outs = self._gather_outs(results, delta)
-        t2 = time.perf_counter()
-        y = jax.block_until_ready(layer.decode(ids, outs))
-        t_decode = time.perf_counter() - t2
-        return y, LayerTiming(t_encode, t_compute, t_decode, worker_times, ids,
-                              layer_name or "")
+        with timed(DECODE) as dec:
+            y = jax.block_until_ready(layer.decode(ids, outs))
+        return y, LayerTiming(enc.s, t_compute, dec.s, worker_times, ids,
+                              layer_name or "",
+                              **_counts(impl, pending, ids))
 
     # -- whole network ------------------------------------------------------
     def dispatch_pipeline_layer(self, idx: int, x,
@@ -477,7 +495,10 @@ class FcdccCluster:
         batch A, so A's master-side collect/decode/transition overlaps B's
         worker compute (round pipelining).  Dispatch order is the only
         thing pipelining changes — each round's arithmetic (and therefore
-        its fp32 bits, for a given survivor subset) is untouched."""
+        its fp32 bits, for a given survivor subset) is untouched.
+
+        Each round takes the next of the cluster's round ids, which tags
+        its spans, its workers' included."""
         pipe = self.get_pipeline(model)
         spec = pipe.specs[idx]
         fused = pipe.fuse_transitions
@@ -486,35 +507,42 @@ class FcdccCluster:
         # preload/run_layer under a colliding layer name must not swap
         # in foreign filters under this pipeline's decode
         ke = pipe.coded_filters[idx]
-
-        t0 = time.perf_counter()
-        if fused and idx > 0:
-            xe = x  # coded shares from the previous round's transition
-            t_encode = 0.0
+        carried = fused and idx > 0  # x: the previous transition's shares
+        bucket = int(x.shape[2] if carried else x.shape[0])
+        round_id = next(self._round_ids)
+        meta = dict(round=round_id, layer=idx, bucket=bucket)
+        phases = {}
+        if carried:
+            xe, t_encode = x, 0.0
         else:
-            xe = jax.block_until_ready(pipe.encoder(idx)(x))
-            t_encode = time.perf_counter() - t0
+            with timed(ENCODE, **meta) as enc:
+                xe = jax.block_until_ready(pipe.encoder(idx)(x))
+            t_encode = phases[ENCODE] = enc.s
 
-        impl = self._pool_impl()
-        fn = lambda i: impl.program(  # noqa: E731
-            spec.program_key, pipe.layers[idx].worker_compute, i,
-            pipe._cluster_programs,
-        )
-        if impl.kind == "device":
-            name = self._model_name(model, pipe)
-            ke = impl.resident_filters(f"{name}/{spec.name}", ke)
-        # first sight of these shapes: compile outside the timed collect so
-        # per-worker timings measure steady state.  Once warmed it's skipped
-        # — the serving hot path must not pay a discarded subtask per layer.
-        wkey = (self.pool, spec.program_key, tuple(xe.shape),
-                tuple(_ke_of(ke, 0).shape))
-        if wkey not in self._warmed:
-            impl.warm(fn, xe, ke)  # outside the lock: warm may compile
-            with self._registry_lock:
-                self._warmed.add(wkey)
-        pending = impl.submit(fn, xe, ke)
+        with timed(SUBMIT, **meta) as sub:
+            impl = self._pool_impl()
+            fn = lambda i: impl.program(  # noqa: E731
+                spec.program_key, pipe.layers[idx].worker_compute, i,
+                pipe._cluster_programs,
+            )
+            if impl.kind == "device":
+                name = self._model_name(model, pipe)
+                ke = impl.resident_filters(f"{name}/{spec.name}", ke)
+            # first sight of these shapes: compile outside the timed collect
+            # so per-worker timings measure steady state.  Once warmed it's
+            # skipped — the serving hot path must not pay a discarded
+            # subtask per layer.
+            wkey = (self.pool, spec.program_key, tuple(xe.shape),
+                    tuple(_ke_of(ke, 0).shape))
+            if wkey not in self._warmed:
+                impl.warm(fn, xe, ke)  # outside the lock: warm may compile
+                with self._registry_lock:
+                    self._warmed.add(wkey)
+            pending = impl.submit(fn, xe, ke, round_id=round_id)
+        phases[SUBMIT] = sub.s
         return PendingRound(idx, pipe, spec, pending, t_encode,
-                            fused_mid=fused and not last)
+                            fused_mid=fused and not last, round=round_id,
+                            bucket=bucket, phases=phases)
 
     def round_ready(self, rnd: PendingRound) -> bool:
         """Non-blocking: would ``collect_pipeline_layer(rnd)`` return
@@ -525,30 +553,31 @@ class FcdccCluster:
         """The reap half: keep the fastest delta of the dispatched round,
         then decode + relu + pool (or the fused partition-resident
         transition).  Returns ``(y, LayerTiming)``."""
-        pipe, spec = rnd.pipe, rnd.spec
+        pipe, spec, idx = rnd.pipe, rnd.spec, rnd.idx
         delta = spec.plan.delta
-        results, worker_times, t_compute = self.collect(rnd.pending, delta)
-
-        ids, outs = self._gather_outs(results, delta)
-        t2 = time.perf_counter()
+        meta = dict(round=rnd.round, layer=idx, bucket=rnd.bucket)
+        with timed(GATHER, **meta) as gather:
+            results, worker_times, t_compute = self.collect(rnd.pending,
+                                                            delta)
+            ids, outs = self._gather_outs(results, delta)
+        with timed(INVERSE, **meta) as inverse:
+            d = jnp.asarray(pipe.decode_matrix(idx, tuple(ids)))
         if rnd.fused_mid:
             # partition-resident transition straight into the next layer's
             # coded shares for ALL n workers (the next collect again keeps
             # whichever delta finish first); the all-n encode columns are a
             # per-layer constant resident on device
-            d = jnp.asarray(pipe.decode_matrix(rnd.idx, tuple(ids)))
-            y = jax.block_until_ready(
-                pipe.transition_fn(rnd.idx)(
-                    outs, d, pipe.encode_columns_all(rnd.idx + 1),
-                )
-            )
+            with timed(TRANSITION, **meta) as dec:
+                y = jax.block_until_ready(pipe.transition_fn(idx)(
+                    outs, d, pipe.encode_columns_all(idx + 1)))
         else:
-            y = jax.block_until_ready(
-                pipe.decoder(rnd.idx, tuple(ids))(outs)
-            )
-        t_decode = time.perf_counter() - t2
-        return y, LayerTiming(rnd.t_encode, t_compute, t_decode, worker_times,
-                              ids, spec.name)
+            with timed(DECODE, **meta) as dec:
+                y = jax.block_until_ready(pipe.decoder_fn(idx)(outs, d))
+        phases = {**rnd.phases, GATHER: gather.s, INVERSE: inverse.s,
+                  dec.name: dec.s}
+        return y, LayerTiming(rnd.t_encode, t_compute, inverse.s + dec.s,
+                              worker_times, ids, spec.name, phases=phases,
+                              **_counts(self._pool_impl(), rnd.pending, ids))
 
     def run_pipeline_layer(self, idx: int, x, model: str | None = None) -> tuple:
         """One ConvL of a loaded pipeline as a full master/worker round:
@@ -610,6 +639,15 @@ class FcdccCluster:
 def _ke_of(ke, i: int):
     """Worker i's filter shard (list = per-device shards, array = master)."""
     return ke[i]
+
+
+def _counts(impl, pending: PendingBatch, ids: list) -> dict:
+    """``LayerTiming``'s subtask counters of a collected round; its
+    ``prep_s`` is what the pool's workers prepared since the last round
+    was collected."""
+    return dict(prep_s=impl.prep.take(), started=pending.started,
+                cancelled=pending.cancelled, used=len(ids),
+                delta_ready_s=pending.delta_ready_s)
 
 
 def run_layer_elastic(plan: FcdccPlan, geo: ConvGeometry, x, k,

@@ -50,10 +50,14 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from concurrent.futures import FIRST_COMPLETED, Future, ThreadPoolExecutor, wait
+from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 
 import jax
 import numpy as np
+
+from repro.core.programs import named
+
+from .spans import WORKER_PREP, WORKER_RUN, WORKER_STRAGGLE, timed
 
 __all__ = [
     "ClusterDegraded", "DeviceWorkerPool", "InjectedWorkerFailure",
@@ -104,9 +108,13 @@ class PendingBatch:
     dispatched device arrays (device pool — filled in under ``lock`` as
     timer-deferred stragglers dispatch).  ``worker_times`` is live — workers
     write into it as they finish — so ``collect`` snapshots it before
-    returning.  ``expected`` (device pool) is the set of live workers whose
-    result will eventually appear; ``errors`` (device pool) holds what a
-    dispatch raised, for ``collect`` to re-raise."""
+    returning; ``finish_t`` (the ``perf_counter`` time a worker finished;
+    on the device pool, when the reaper first saw its result) is live the
+    same way.
+    ``expected`` is the set of live workers; ``errors`` (device pool) holds
+    what a dispatch raised, for ``collect`` to re-raise.  ``collect`` fills
+    in ``cancelled`` (subtasks it cancelled before they started) and
+    ``delta_ready_s`` (submit to the delta-th finish)."""
 
     futures: dict
     results: dict  # guarded-by: self.lock
@@ -115,6 +123,49 @@ class PendingBatch:
     expected: set | None = None
     lock: threading.Lock | None = None
     errors: list = dataclasses.field(default_factory=list)  # guarded-by: self.lock
+    finish_t: list = dataclasses.field(default_factory=list)  # guarded-by: single-writer-slots
+    cancelled: int = 0  # guarded-by: collect-thread
+    delta_ready_s: float = float("nan")  # guarded-by: collect-thread
+
+    @property
+    def started(self) -> int:
+        """Subtasks that reached the device: live workers minus those
+        ``collect`` cancelled before they started."""
+        return len(self.expected) - self.cancelled
+
+    @staticmethod
+    def open(delays, lock: threading.Lock | None = None) -> "PendingBatch":
+        """An empty batch of ``len(delays)`` subtasks submitted now: dead
+        (infinite-delay) workers read inf, live ones nan until they
+        finish."""
+        n = len(delays)
+        live = {i for i in range(n) if np.isfinite(delays[i])}
+        return PendingBatch(
+            {}, {}, [float("nan") if i in live else float("inf")
+                     for i in range(n)],
+            time.perf_counter(), expected=live, lock=lock,
+            finish_t=[float("nan")] * n)
+
+
+class PrepTally:
+    """The workers' share-preparation seconds, added by whichever thread
+    prepared a share and drained by the collecting one.  A subtask that
+    prepares after its round was collected (a straggler, a deferred
+    dispatch) lands in a later round's count instead of in none."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._s = 0.0  # guarded-by: self._lock
+
+    def add(self, s: float) -> None:
+        with self._lock:
+            self._s += s
+
+    def take(self) -> float:
+        """The seconds added since the last ``take``."""
+        with self._lock:
+            s, self._s = self._s, 0.0
+        return s
 
 
 def resolve_pool(pool: str | None, mode: str, devices=None) -> str:
@@ -164,6 +215,7 @@ class ThreadWorkerPool:
         self.n = n
         self.straggler = straggler
         self.mode = mode
+        self.prep = PrepTally()
         # lazy create (first submit) vs shutdown swap race from another
         # thread: both transitions go through the lock
         self._lifecycle_lock = threading.Lock()
@@ -194,7 +246,7 @@ class ThreadWorkerPool:
         cluster's cache); per-worker specialization is a device-pool thing."""
         fn = jit_cache.get(key)
         if fn is None:
-            fn = jit_cache[key] = jax.jit(raw)
+            fn = jit_cache[key] = jax.jit(named(raw, "worker"))
         return fn
 
     def resident_filters(self, name: str, ke):
@@ -212,36 +264,33 @@ class ThreadWorkerPool:
         jax.block_until_ready(fn(0)(xe[0], _ke_of(ke, 0)))
 
     # -- dispatch / reap ---------------------------------------------------
-    def submit(self, fn, xe, ke) -> PendingBatch:
+    def submit(self, fn, xe, ke, round_id: int = -1) -> PendingBatch:
         delays = self.straggler.delays
-        worker_times = [
-            float("inf") if not np.isfinite(delays[i]) else float("nan")
-            for i in range(self.n)
-        ]
+        pending = PendingBatch.open(delays)
 
         def work(i):
-            if not np.isfinite(delays[i]):
+            if i not in pending.expected:
                 raise InjectedWorkerFailure(f"worker {i} failed")
-            t = time.perf_counter()
-            out = jax.block_until_ready(fn(i)(xe[i], _ke_of(ke, i)))
-            dt = time.perf_counter() - t
+            with timed(WORKER_PREP, round=round_id, worker=i) as prep:
+                xe_i, ke_i = xe[i], _ke_of(ke, i)
+            self.prep.add(prep.s)
+            with timed(WORKER_RUN, round=round_id, worker=i) as run:
+                out = jax.block_until_ready(fn(i)(xe_i, ke_i))
             if self.mode == "threads" and delays[i] > 0:
-                time.sleep(delays[i])
-            worker_times[i] = dt + delays[i]
+                with timed(WORKER_STRAGGLE, round=round_id, worker=i):
+                    time.sleep(delays[i])
+            pending.finish_t[i] = time.perf_counter()
+            pending.worker_times[i] = prep.s + run.s + delays[i]
             return i, out
 
-        t_start = time.perf_counter()
-        futures: dict[int, Future] = {}
-        results: dict[int, object] = {}
         if self.mode == "threads":
             pools = self._ensure_pools()
-            futures = {i: pools[i].submit(work, i) for i in range(self.n)}
+            pending.futures.update(
+                (i, pools[i].submit(work, i)) for i in range(self.n))
         else:  # simulated clock: compute all live workers synchronously
-            for i in range(self.n):
-                if np.isfinite(delays[i]):
-                    _, out = work(i)
-                    results[i] = out
-        return PendingBatch(futures, results, worker_times, t_start)
+            for i in sorted(pending.expected):
+                _, pending.results[i] = work(i)
+        return pending
 
     def ready(self, pending: PendingBatch, delta: int) -> bool:
         """Non-blocking: would ``collect`` return without waiting?  True
@@ -277,8 +326,13 @@ class ThreadWorkerPool:
                         raise
                     results[i] = out
             t_compute = time.perf_counter() - pending.t_start
-            for f in outstanding:  # abandon stragglers, don't join them
-                f.cancel()
+            # abandon stragglers, don't join them; a subtask still queued
+            # behind its worker's previous one never starts
+            pending.cancelled = sum(f.cancel() for f in outstanding)
+            finished = sorted(pending.finish_t[i] for i in results)
+            pending.delta_ready_s = (
+                finished[min(delta, len(finished)) - 1] - pending.t_start
+                if finished else t_compute)
         else:  # completion time = max simulated clock over the chosen delta
             order = sorted(results, key=lambda i: pending.worker_times[i])
             results = {i: results[i] for i in order[:delta]}
@@ -286,6 +340,7 @@ class ThreadWorkerPool:
                 max(pending.worker_times[i] for i in results)
                 if results else float("inf")
             )
+            pending.delta_ready_s = t_compute
         return results, list(pending.worker_times), t_compute
 
 
@@ -314,6 +369,7 @@ class DeviceWorkerPool:
         self.devices = worker_devices(self.mesh, n)  # len n (round-robin)
         # decode runs on the master device: where the default jit places it
         self.master = jax.devices()[0]
+        self.prep = PrepTally()
         # None = adaptive exponential backoff; a number = fixed period
         # (kept as the deterministic override for tests)
         self._poll_interval_s = poll_interval_s
@@ -350,7 +406,8 @@ class DeviceWorkerPool:
         with self._state_lock:
             fn = self._programs.get((key, dev))
             if fn is None:
-                fn = self._programs[(key, dev)] = jax.jit(raw)
+                fn = self._programs[(key, dev)] = jax.jit(
+                    named(raw, "worker"))
             return fn
 
     def program_traces(self) -> dict:
@@ -400,17 +457,9 @@ class DeviceWorkerPool:
             o.block_until_ready()
 
     # -- dispatch / reap ---------------------------------------------------
-    def submit(self, fn, xe, ke) -> PendingBatch:
+    def submit(self, fn, xe, ke, round_id: int = -1) -> PendingBatch:
         delays = self.straggler.delays
-        worker_times = [
-            float("inf") if not np.isfinite(delays[i]) else float("nan")
-            for i in range(self.n)
-        ]
-        results: dict[int, object] = {}
-        lock = threading.Lock()
-        t_start = time.perf_counter()
-        pending = PendingBatch({}, results, worker_times, t_start,
-                               expected=set(), lock=lock)
+        pending = PendingBatch.open(delays, lock=threading.Lock())
 
         def dispatch(i):
             # async: enqueues on device i's queue and returns immediately;
@@ -418,19 +467,20 @@ class DeviceWorkerPool:
             # (a deferred dispatch runs on a timer thread, where it would
             # otherwise vanish and leave collect waiting forever)
             try:
-                out = fn(i)(jax.device_put(xe[i], self.devices[i]),
-                            _ke_of(ke, i))
+                with timed(WORKER_PREP, round=round_id, worker=i) as prep:
+                    xe_i = jax.device_put(xe[i], self.devices[i])
+                    ke_i = _ke_of(ke, i)
+                self.prep.add(prep.s)
+                with timed(WORKER_RUN, round=round_id, worker=i):
+                    out = fn(i)(xe_i, ke_i)
             except Exception as err:
                 with pending.lock:
                     pending.errors.append(err)
                 return
-            with lock:
-                results[i] = out
+            with pending.lock:
+                pending.results[i] = out
 
-        for i in range(self.n):
-            if not np.isfinite(delays[i]):
-                continue  # dead worker: never dispatched
-            pending.expected.add(i)
+        for i in sorted(pending.expected):  # dead workers: never dispatched
             if delays[i] > 0:
                 # injected straggler = delayed dispatch (simulated network/
                 # queueing delay ahead of the subtask)
@@ -483,8 +533,9 @@ class DeviceWorkerPool:
             for i, a in avail.items():
                 if a.is_ready():
                     reaped[i] = a
+                    pending.finish_t[i] = time.perf_counter()
                     pending.worker_times[i] = \
-                        time.perf_counter() - pending.t_start
+                        pending.finish_t[i] - pending.t_start
                     progressed = True
                     if len(reaped) >= delta:
                         break
@@ -498,6 +549,9 @@ class DeviceWorkerPool:
                 time.sleep(sleep_s)
                 sleep_s = min(sleep_s * 2, self._POLL_MAX)
         t_compute = time.perf_counter() - pending.t_start
+        # the reaper's first sight of the delta-th result
+        pending.delta_ready_s = max(
+            (pending.worker_times[i] for i in reaped), default=t_compute)
         return reaped, list(pending.worker_times), t_compute
 
 

@@ -62,6 +62,8 @@ import numpy as np
 
 from repro.core.pipeline import CodedPipeline, build_cnn_pipeline
 from repro.runtime import FcdccCluster, PendingRound, StragglerModel
+from repro.runtime.spans import (ADMIT, COMPLETE, ENCODE, GATHER, IDLE,
+                                 REAP_WAIT, SUBMIT, timed)
 
 from .metrics import (MetricsCollector, OverlapStats, RequestRecord,
                       ServingStats)
@@ -102,7 +104,6 @@ class _InFlightRound:
     state: _ModelState
     batch: ScheduledBatch
     rnd: PendingRound
-    dispatch_s: float  # master-side encode + submit time for this round
 
 
 class CodedServer:
@@ -490,6 +491,9 @@ class CodedServer:
         # oldest first (collects happen in whatever order rounds finish)
         rounds: list[_InFlightRound] = []  # guarded-by: engine-thread
         busy_t0 = 0.0  # wall-clock start of the current busy span
+        # the cluster's id of the latest dispatched round: every span of a
+        # round carries its own, the engine's spans the latest one's
+        last_round = -1
         while True:
             if self._stop.is_set() and (
                 not self._drain or (not rounds and not sched.has_work())
@@ -497,16 +501,19 @@ class CodedServer:
                 # drain=False abandons in-flight rounds: their results are
                 # never gathered and cancel_all below fails their requests
                 break
-            # layer boundary: admit late arrivals (all models, rotating)
-            # until every queue is empty or every inflight slot is filled —
-            # a single admit per iteration would fill free capacity one
-            # layer-round late
-            while sched.admit() is not None:
-                pass
-            # re-pack equal-depth fragments into full buckets (batches with
-            # a round in flight are skipped — their state is mid-round)
-            for name, merges in sched.coalesce().items():
-                self.metrics.count_coalesced(name, merges)
+            with timed(ADMIT, round=last_round) as span:
+                # layer boundary: admit late arrivals (all models, rotating)
+                # until every queue is empty or every inflight slot is
+                # filled — a single admit per iteration would fill free
+                # capacity one layer-round late
+                while sched.admit() is not None:
+                    pass
+                # re-pack equal-depth fragments into full buckets (batches
+                # with a round in flight are skipped — their state is
+                # mid-round)
+                for name, merges in sched.coalesce().items():
+                    self.metrics.count_coalesced(name, merges)
+            self.metrics.note_phase(span.name, span.s)
             # dispatch phase: fill the window with fair-share picks, each
             # pick one layer round, so batch B's workers start before
             # batch A's collect
@@ -528,7 +535,6 @@ class CodedServer:
                     except Exception as err:  # degraded cluster etc.
                         self._fail_batch(name, batch, err)
                     break  # synchronous: back to admission, like depth 1
-                t0 = time.perf_counter()
                 try:
                     rnd = self.cluster.dispatch_pipeline_layer(
                         batch.layer_idx, batch.x, name
@@ -536,20 +542,25 @@ class CodedServer:
                 except Exception as err:  # encode/submit failed
                     self._fail_batch(name, batch, err)
                     continue
+                last_round = rnd.round
                 batch.dispatched = True
-                rounds.append(_InFlightRound(
-                    state, batch, rnd, time.perf_counter() - t0
-                ))
+                rounds.append(_InFlightRound(state, batch, rnd))
                 self.metrics.note_depth(len(rounds))
             if not rounds:
+                span = None
                 if not self._stop.is_set():
                     with sched.not_empty:
                         if not sched.queued() and not self._stop.is_set():
-                            sched.not_empty.wait(self._poll_interval_s)
+                            with timed(IDLE, round=last_round) as span:
+                                sched.not_empty.wait(self._poll_interval_s)
+                if span is not None:
+                    self.metrics.note_phase(span.name, span.s)
                 continue
-            ent = self._poll_rounds(
-                rounds, can_dispatch=len(rounds) < self.pipeline_depth
-            )
+            with timed(REAP_WAIT, round=last_round) as span:
+                ent = self._poll_rounds(
+                    rounds, can_dispatch=len(rounds) < self.pipeline_depth
+                )
+            self.metrics.note_phase(span.name, span.s)
             if ent is None:
                 continue  # new dispatchable work, or stop without drain
             self._finish_round(ent)
@@ -606,15 +617,12 @@ class CodedServer:
         fence, ``finish`` is first-writer-wins, and retire tolerates the
         missing scheduler."""
         state, batch, pipe = ent.state, ent.batch, ent.rnd.pipe
-        t0 = time.perf_counter()
         try:
             y, timing = self.cluster.collect_pipeline_layer(ent.rnd)
         except Exception as err:  # degraded cluster etc: fail the batch
             self._fail_batch(state.name, batch, err)
             return
-        t_reap = time.perf_counter() - t0
         batch.x = y
-        batch.timings.append(timing)
         batch.layer_idx += 1
         # partition-resident pipelines carry coded shares between rounds —
         # the request batch sits on axis 2 of (n, ell_a, B, C, h_hat, Wp)
@@ -624,42 +632,32 @@ class CodedServer:
             and 0 < batch.layer_idx < len(pipe.specs) else 0
         )
         batch.dispatched = False
+        ph = timing.phases
         self.metrics.record_phases(
             state.name,
-            dispatch_s=ent.dispatch_s,
+            dispatch_s=ph.get(ENCODE, 0.0) + ph[SUBMIT],
             worker_s=timing.compute_s,
-            collect_s=max(t_reap - timing.decode_s, 0.0),
+            collect_s=ph[GATHER],
             transition_s=timing.decode_s,
+            prep_s=timing.prep_s, started=timing.started, used=timing.used,
+            cancelled=timing.cancelled, delta_ready_s=timing.delta_ready_s,
+            phases=ph,
         )
         if batch.layer_idx >= len(pipe.specs):
-            self._complete(state, batch)
+            with timed(COMPLETE, round=ent.rnd.round,
+                       bucket=batch.bucket) as span:
+                self._complete(state, batch)
+            self.metrics.note_phase(span.name, span.s)
 
     def _advance(self, state: _ModelState, batch: ScheduledBatch) -> None:
-        """Advance one batch — by one ConvL (cluster execution, so other
-        batches and new arrivals of any model interleave at layer
-        boundaries) or through the whole prepared stack (direct)."""
+        """Direct execution: run one batch through the whole prepared
+        stack and complete it."""
         pipe = state.pipeline
-        if self.execution == "direct":
-            batch.x = jax.block_until_ready(
-                pipe.run_prepared(batch.x, self._direct_plan(state))
-            )
-            batch.layer_idx = len(pipe.specs)
-        else:
-            batch.x, timing = self.cluster.run_pipeline_layer(
-                batch.layer_idx, batch.x, state.name
-            )
-            batch.timings.append(timing)
-            batch.layer_idx += 1
-            # partition-resident pipelines carry coded shares between
-            # rounds — the request batch sits on axis 2 of
-            # (n, ell_a, B, C, h_hat, Wp) until the final merge, and
-            # coalescing/padding must slice that axis
-            batch.batch_axis = (
-                2 if pipe.fuse_transitions
-                and 0 < batch.layer_idx < len(pipe.specs) else 0
-            )
-        if batch.layer_idx >= len(pipe.specs):
-            self._complete(state, batch)
+        batch.x = jax.block_until_ready(
+            pipe.run_prepared(batch.x, self._direct_plan(state))
+        )
+        batch.layer_idx = len(pipe.specs)
+        self._complete(state, batch)
 
     def _complete(self, state: _ModelState, batch: ScheduledBatch) -> None:
         self.scheduler.retire(state.name, batch)

@@ -92,7 +92,14 @@ class OverlapStats:
     form of the pipelining win: serial phase seconds per busy wall second —
     ~1.0 at depth 1 (phases ARE the wall), > 1.0 when master-side
     collect/transition of one batch overlapped another batch's worker
-    compute."""
+    compute.
+
+    The subtask counters say how much of the worker pool's work a round
+    used: of the n coded subtasks, ``subtasks_started`` reached the device,
+    ``subtasks_cancelled`` were cancelled before they started, and
+    ``subtasks_used`` (delta per round) were decoded from.
+    ``longest_phase_s`` is the longest single span of the engine thread
+    (``repro.runtime.spans``; ``longest_phase`` names it), engine-wide."""
 
     rounds: int            # collected worker rounds
     dispatch_s: float      # sum: master-side encode + submit
@@ -101,6 +108,13 @@ class OverlapStats:
     transition_s: float    # sum: decode or fused transition
     busy_wall_s: float     # wall time with >= 1 round in flight
     max_depth: int         # deepest pipeline window actually reached
+    subtasks_started: int = 0
+    subtasks_used: int = 0
+    subtasks_cancelled: int = 0
+    prep_s: float = 0.0         # sum: every started share preparation
+    delta_ready_s: float = 0.0  # sum: submit -> delta-th worker finish
+    longest_phase_s: float = 0.0
+    longest_phase: str = ""
 
     @property
     def serial_s(self) -> float:
@@ -125,10 +139,11 @@ class MetricsCollector:
         self._lock = threading.Lock()
         self._records: list[RequestRecord] = []  # guarded-by: self._lock
         self._coalesced: dict[str, int] = {}  # guarded-by: self._lock
-        # per-model round phase tuples (dispatch, worker, collect, transition)
+        # per-model round tuples, in ``_ROUND_FIELDS`` order
         self._phases: dict[str, list[tuple]] = {}  # guarded-by: self._lock
         self._busy_wall_s: float = 0.0  # guarded-by: self._lock
         self._max_depth: int = 0  # guarded-by: self._lock
+        self._longest: tuple[float, str] = (0.0, "")  # guarded-by: self._lock
 
     def record(self, rec: RequestRecord) -> None:
         with self._lock:
@@ -139,13 +154,31 @@ class MetricsCollector:
         with self._lock:
             self._coalesced[model] = self._coalesced.get(model, 0) + merges
 
+    # what ``record_phases`` keeps of a round: OverlapStats sums these
+    _ROUND_FIELDS = ("dispatch_s", "worker_s", "collect_s", "transition_s",
+                     "subtasks_started", "subtasks_used",
+                     "subtasks_cancelled", "prep_s", "delta_ready_s")
+
     def record_phases(self, model: str, *, dispatch_s: float, worker_s: float,
-                      collect_s: float, transition_s: float) -> None:
-        """One collected worker round's phase breakdown (engine thread)."""
+                      collect_s: float, transition_s: float,
+                      prep_s: float = 0.0, started: int = 0, used: int = 0,
+                      cancelled: int = 0, delta_ready_s: float = 0.0,
+                      phases: dict | None = None) -> None:
+        """One collected worker round's phase breakdown and subtask
+        counters (engine thread); ``phases`` are its engine-thread span
+        seconds by name, for the longest span."""
         with self._lock:
             self._phases.setdefault(model, []).append(
-                (dispatch_s, worker_s, collect_s, transition_s)
+                (dispatch_s, worker_s, collect_s, transition_s, started,
+                 used, cancelled, prep_s, delta_ready_s)
             )
+            for name, secs in (phases or {}).items():
+                self._longest = max(self._longest, (secs, name))
+
+    def note_phase(self, name: str, secs: float) -> None:
+        """One engine-thread span outside a round's own phases."""
+        with self._lock:
+            self._longest = max(self._longest, (secs, name))
 
     def note_busy(self, wall_s: float) -> None:
         """Close one busy span: ``wall_s`` seconds with >= 1 round in
@@ -168,12 +201,13 @@ class MetricsCollector:
             else:
                 phases = list(self._phases.get(model, []))
             busy, depth = self._busy_wall_s, self._max_depth
-        sums = [sum(p[k] for p in phases) for k in range(4)] \
-            if phases else [0.0] * 4
+            longest_s, longest = self._longest
+        sums = {f: sum((p[k] for p in phases),
+                       0 if f.startswith("subtasks_") else 0.0)
+                for k, f in enumerate(self._ROUND_FIELDS)}
         return OverlapStats(
-            rounds=len(phases), dispatch_s=sums[0], worker_s=sums[1],
-            collect_s=sums[2], transition_s=sums[3],
-            busy_wall_s=busy, max_depth=depth,
+            rounds=len(phases), busy_wall_s=busy, max_depth=depth,
+            longest_phase_s=longest_s, longest_phase=longest, **sums,
         )
 
     def records(self, model: str | None = None) -> list[RequestRecord]:
@@ -196,6 +230,7 @@ class MetricsCollector:
             self._phases.clear()
             self._busy_wall_s = 0.0
             self._max_depth = 0
+            self._longest = (0.0, "")
 
     def coalesced(self, model: str | None = None) -> int:
         with self._lock:

@@ -185,7 +185,6 @@ class ScheduledBatch:
     bucket: int
     layer_idx: int = 0
     model: str = ""
-    timings: list = dataclasses.field(default_factory=list)
     # which axis of ``x`` is the request batch: 0 for raw/merged tensors,
     # 2 while carrying partition-resident coded shares between layers
     batch_axis: int = 0
@@ -345,8 +344,6 @@ class Scheduler:
                                else self.pad_to_bucket(x, axis=ax))
                     a.requests.extend(b.requests)
                     a.x, a.bucket = x, int(x.shape[ax])
-                    # a's timings describe the merged batch's past; b's are
-                    # dropped with b (only per-request metrics survive)
                     self.inflight.remove(b)
                     group.pop(1)
                     group.sort(key=lambda b: b.real)
