@@ -46,6 +46,7 @@ from .crme import recovery_matrix
 from .fcdcc import FcdccPlan
 from .nsctc import encode_tensor_list, group_by_worker
 from .pipeline import ProgramCell
+from .programs import worker_share_program
 
 __all__ = [
     "GemmGeometry",
@@ -361,9 +362,8 @@ class CodedDecoderPipeline:
         fn = cache.get(key)
         if fn is None:
             compute = self.layers[idx].worker_compute
-            fn = cache[key] = jax.jit(
-                jax.vmap(compute) if over_workers else compute
-            )
+            fn = cache[key] = (jax.jit(jax.vmap(compute)) if over_workers
+                               else worker_share_program(compute))
         return fn
 
     def decode_matrix(self, idx: int, worker_ids: tuple[int, ...]) -> np.ndarray:
@@ -653,8 +653,9 @@ class CodedDecoderPipeline:
                             cid("worker"), "worker", mode, idx, bucket,
                             spec.program_key,
                             self.worker_program(idx, over_workers=False),
-                            (sds((ea, bucket, d_in), f32),
-                             sds((eb, d_in, ob), f32)))
+                            (sds((self.n, ea, bucket, d_in), f32),
+                             sds((self.n, eb, d_in, ob), f32),
+                             sds((), jnp.int32)))
                     yield ProgramCell(
                         cid("decoder"), "decoder", mode, idx, bucket,
                         ("dec",), self.decoder_fn(idx),

@@ -37,7 +37,7 @@ from .crme import recovery_matrix
 from .fcdcc import CodedConv2d, FcdccPlan
 from .nsctc import encode_tensor_list, group_by_worker
 from .partition import ConvGeometry, merge_output, partition_transition
-from .programs import named
+from .programs import named, worker_share_program
 
 __all__ = [
     "CodedLayerSpec",
@@ -407,7 +407,9 @@ class CodedPipeline:
 
         ``over_workers=True`` gives the vmapped-over-the-worker-axis program
         (the single-process path); ``False`` gives the one-worker program the
-        threaded cluster dispatches per worker.  Layers with the same
+        threaded cluster serves, ``(xe, ke, i)`` on the stacked shares with
+        the worker index traced (``worker_share_program``; the thread pool
+        fills the same cache entry with it).  Layers with the same
         ``program_key`` share one program — jit's shape cache handles the
         per-geometry specialization, so e.g. VGG-16's thirteen ConvLs run on
         a handful of compiled programs.
@@ -417,9 +419,8 @@ class CodedPipeline:
         fn = cache.get(key)
         if fn is None:
             compute = self.layers[idx].worker_compute
-            fn = cache[key] = jax.jit(
-                jax.vmap(compute) if over_workers else compute
-            )
+            fn = cache[key] = (jax.jit(jax.vmap(compute)) if over_workers
+                               else worker_share_program(compute))
         return fn
 
     def encode_columns(self, idx: int, worker_ids: tuple[int, ...]) -> np.ndarray:
@@ -704,12 +705,17 @@ class CodedPipeline:
                              jax.ShapeDtypeStruct(
                                 (delta,) + ke_shape, dtype)))
                     else:
+                        # the served signature: every worker's shares and
+                        # the worker index, selected inside the program
                         yield ProgramCell(
                             cid("worker"), "worker", mode, idx, bucket,
                             spec.program_key,
                             self.worker_program(idx, over_workers=False),
-                            (jax.ShapeDtypeStruct(xe.shape[1:], xe.dtype),
-                             jax.ShapeDtypeStruct(ke_shape, dtype)))
+                            (jax.ShapeDtypeStruct(
+                                (self.n,) + xe.shape[1:], xe.dtype),
+                             jax.ShapeDtypeStruct(
+                                (self.n,) + ke_shape, dtype),
+                             jax.ShapeDtypeStruct((), jnp.int32)))
                     outs = jax.eval_shape(
                         jax.vmap(layer.worker_compute),
                         jax.ShapeDtypeStruct((delta,) + xe.shape[1:],

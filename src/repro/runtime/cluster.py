@@ -33,10 +33,11 @@ Entry points:
   * ``run_layer`` — one FCDCC ConvL end-to-end with timing breakdown
     (encode / upload / compute / download / decode), simulated-clock mode
     for deterministic tests and real-thread mode for wall-clock numbers.
-  * ``submit`` / ``collect`` — the asynchronous master: dispatch n coded
-    subtasks without blocking, then reap the fastest delta later.  The
-    serving engine (``repro.serving``) uses this split to interleave
-    layers of different in-flight request batches on one executor.
+  * ``dispatch_pipeline_layer`` / ``collect_pipeline_layer`` — the
+    asynchronous master: dispatch a layer's n coded subtasks without
+    blocking, then reap the fastest delta later.  The serving engine
+    (``repro.serving``) uses this split to interleave layers of different
+    in-flight request batches on one executor.
   * ``load_pipeline`` / ``run_pipeline`` / ``run_pipeline_layer`` — stream
     a whole CNN ConvL stack (a ``repro.core.pipeline.CodedPipeline`` with
     resident coded filters) through the cluster for batched
@@ -251,18 +252,6 @@ class FcdccCluster:
                 )
             return layer
 
-    def worker_program(self, layer: CodedConv2d):
-        """Jitted one-worker program on the master device, shared by layers
-        with the same signature (re-jit across ``run_layer`` calls
-        eliminated).  The device pool compiles its own per-device twins of
-        the same callable (``DeviceWorkerPool.program``)."""
-        key = (layer.plan.ell_a, layer.plan.ell_b, layer.geo.stride)
-        with self._registry_lock:
-            fn = self._programs.get(key)
-            if fn is None:
-                fn = self._programs[key] = jax.jit(layer.worker_compute)
-            return fn
-
     @staticmethod
     def _filter_code_key(plan: FcdccPlan, geo: ConvGeometry) -> tuple:
         """The parts of (plan, geo) that determine ``encode_filters`` output.
@@ -361,25 +350,9 @@ class FcdccCluster:
         return "default"
 
     # -- fastest-delta collection ------------------------------------------
-    def submit(self, compute_one, xe, ke) -> PendingBatch:
-        """Dispatch n coded subtasks without waiting (the asynchronous
-        master's send phase).  The thread pool submits one subtask per
-        worker onto its persistent per-worker executors (simulated mode
-        computes every live worker's result now and lets ``collect`` pick
-        by simulated clock); the device pool async-dispatches each subtask
-        onto its worker's own device queue.  Pair with ``collect``;
-        ``run_layer``/``run_pipeline`` do.
-
-        ``worker_times`` starts as inf for dead workers and nan for live
-        ones; a worker overwrites its slot only when it finishes.  A
-        ``collect`` snapshot therefore reads inf = dead, nan = discarded
-        before finishing, finite = measured — a dead node can never be
-        mistaken for the fastest one."""
-        return self._pool_impl().submit(lambda i: compute_one, xe, ke)
-
     def collect(self, pending: PendingBatch, delta: int, *,
                 block: bool = True):
-        """Reap the fastest ``delta`` results of a ``submit``; returns
+        """Reap the fastest ``delta`` results of a pool's ``submit``; returns
         ``(results, worker_times, t_compute)``.  Later arrivals are
         discarded, exactly like the paper's asynchronous collection —
         straggler subtasks are never joined (their own node stays busy
@@ -400,11 +373,6 @@ class FcdccCluster:
                 f"gamma={self.n - delta} exceeded"
             )
         return results, worker_times, t_compute
-
-    def _collect(self, compute_one, xe, ke, n: int, delta: int):
-        """Submit + collect in one blocking call (the pre-serving API)."""
-        assert n == self.n, (n, self.n)
-        return self.collect(self.submit(compute_one, xe, ke), delta)
 
     def _gather_outs(self, results: dict, delta: int):
         """The surviving-shard gather feeding decode: the fastest delta
@@ -466,7 +434,7 @@ class FcdccCluster:
         # warm the kernel on first sight of these shapes so per-worker
         # timings measure steady state (skipped once warmed — re-running
         # would execute a whole discarded subtask, not a cache no-op)
-        wkey = (self.pool,) + pkey + (tuple(xe.shape), tuple(_ke_of(ke, 0).shape))
+        wkey = (self.pool,) + pkey + (tuple(xe.shape), _share_shape(ke))
         if wkey not in self._warmed:
             impl.warm(fn, xe, ke)  # outside the lock: warm may compile
             with self._registry_lock:
@@ -533,7 +501,7 @@ class FcdccCluster:
             # skipped — the serving hot path must not pay a discarded
             # subtask per layer.
             wkey = (self.pool, spec.program_key, tuple(xe.shape),
-                    tuple(_ke_of(ke, 0).shape))
+                    _share_shape(ke))
             if wkey not in self._warmed:
                 impl.warm(fn, xe, ke)  # outside the lock: warm may compile
                 with self._registry_lock:
@@ -636,9 +604,10 @@ class FcdccCluster:
         return (x[0] if squeeze else x), timings
 
 
-def _ke_of(ke, i: int):
-    """Worker i's filter shard (list = per-device shards, array = master)."""
-    return ke[i]
+def _share_shape(ke) -> tuple:
+    """One worker's filter-shard shape (list = per-device shards, array =
+    the stacked master copy), read without slicing on the device."""
+    return tuple(ke[0].shape if isinstance(ke, list) else ke.shape[1:])
 
 
 def _counts(impl, pending: PendingBatch, ids: list) -> dict:

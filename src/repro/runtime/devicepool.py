@@ -6,10 +6,13 @@ master/worker round:
   * ``ThreadWorkerPool`` (``pool="threads"``) — the original simulated
     cluster: one persistent single-thread executor per worker, every
     subtask computed on the *default* JAX device, stragglers injected as
-    ``sleep()``s after the compute.  Deterministic, runs anywhere, and the
-    only choice for ``mode="simulated"`` — but the n subtasks serialize on
-    one device queue, so the paper's parallel decomposition never actually
-    runs in parallel.
+    ``sleep()``s after the compute.  Each worker hands its program the
+    whole stacked coded inputs and filters and its own index, and the
+    program selects the worker's share on the device
+    (``core.programs.worker_share_program``).  Deterministic, runs
+    anywhere, and the only choice for ``mode="simulated"`` — but the n
+    subtasks serialize on one device queue, so the paper's parallel
+    decomposition never actually runs in parallel.
   * ``DeviceWorkerPool`` (``pool="device"``) — each worker pinned to a
     ``jax.Device`` from a 1-D worker mesh (``launch.mesh.make_worker_mesh``
     / ``sharding.worker_devices``): real TPU/GPU devices, or CPU host
@@ -55,7 +58,7 @@ from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 import jax
 import numpy as np
 
-from repro.core.programs import named
+from repro.core.programs import named, worker_share_program
 
 from .spans import WORKER_PREP, WORKER_RUN, WORKER_STRAGGLE, timed
 
@@ -216,6 +219,10 @@ class ThreadWorkerPool:
         self.straggler = straggler
         self.mode = mode
         self.prep = PrepTally()
+        # each worker's index as a device scalar, made once: the program
+        # selects the worker's share with it, and a Python int would cost
+        # a host-to-device copy every subtask
+        self._index = [jax.device_put(np.int32(i)) for i in range(n)]
         # lazy create (first submit) vs shutdown swap race from another
         # thread: both transitions go through the lock
         self._lifecycle_lock = threading.Lock()
@@ -243,10 +250,12 @@ class ThreadWorkerPool:
     # -- program/filter placement ------------------------------------------
     def program(self, key: tuple, raw, i: int, jit_cache: dict):
         """All workers share ONE jitted program on the default device (the
-        cluster's cache); per-worker specialization is a device-pool thing."""
+        cluster's cache), which selects each worker's share from the
+        stacked arrays by a traced index (``worker_share_program``);
+        per-worker specialization is a device-pool thing."""
         fn = jit_cache.get(key)
         if fn is None:
-            fn = jit_cache[key] = jax.jit(named(raw, "worker"))
+            fn = jit_cache[key] = worker_share_program(raw)
         return fn
 
     def resident_filters(self, name: str, ke):
@@ -260,8 +269,9 @@ class ThreadWorkerPool:
 
     def warm(self, fn, xe, ke) -> None:
         """Compile outside the timed collect: one worker-0 call suffices —
-        every worker runs the same program on the same device."""
-        jax.block_until_ready(fn(0)(xe[0], _ke_of(ke, 0)))
+        every worker runs the same program on the same device, its index
+        a traced argument."""
+        jax.block_until_ready(fn(0)(xe, ke, self._index[0]))
 
     # -- dispatch / reap ---------------------------------------------------
     def submit(self, fn, xe, ke, round_id: int = -1) -> PendingBatch:
@@ -272,10 +282,10 @@ class ThreadWorkerPool:
             if i not in pending.expected:
                 raise InjectedWorkerFailure(f"worker {i} failed")
             with timed(WORKER_PREP, round=round_id, worker=i) as prep:
-                xe_i, ke_i = xe[i], _ke_of(ke, i)
+                program, index = fn(i), self._index[i]
             self.prep.add(prep.s)
             with timed(WORKER_RUN, round=round_id, worker=i) as run:
-                out = jax.block_until_ready(fn(i)(xe_i, ke_i))
+                out = jax.block_until_ready(program(xe, ke, index))
             if self.mode == "threads" and delays[i] > 0:
                 with timed(WORKER_STRAGGLE, round=round_id, worker=i):
                     time.sleep(delays[i])

@@ -311,13 +311,13 @@ def test_server_surfaces_worker_faults_tolerates_dead_workers(fault):
     else:
         # the worker program both layers share (one program key): a device
         # run that fails once armed, as an XLA runtime error would
-        compiled = jax.jit(pipe.layers[0].worker_compute)
+        compiled = pipe.worker_program(0, over_workers=False)
 
-        def program(xe_i, ke_i):
+        def program(xe, ke, i):
             if armed.is_set():
                 raise jax.errors.JaxRuntimeError(
                     "INTERNAL: injected device fault")
-            return compiled(xe_i, ke_i)
+            return compiled(xe, ke, i)
 
         pipe._cluster_programs[pipe.specs[0].program_key] = program
     server = CodedServer(pipe, StragglerModel(delays), mode="threads",

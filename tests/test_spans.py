@@ -123,17 +123,32 @@ def test_subtask_counters_with_straggler_threads():
 
 
 class _SlowShares:
-    """Coded shares whose slicing takes ``secs`` and is logged."""
+    """Coded shares whose slicing takes ``secs`` and is logged (the device
+    pool slices each worker's share on the host)."""
 
     def __init__(self, secs):
         self.secs = secs
-        self.sliced = []  # list.append: one atomic step per worker thread
+        self.log = []  # list.append: one atomic step per worker thread
         self.share = jnp.zeros(4)
 
     def __getitem__(self, i):
         time.sleep(self.secs)
-        self.sliced.append(i)
+        self.log.append(i)
         return self.share
+
+
+class _SlowPrograms:
+    """Worker programs whose picking takes ``secs`` and is logged (the
+    thread pool's preparation: its program selects the share itself)."""
+
+    def __init__(self, secs):
+        self.secs = secs
+        self.log = []  # list.append: one atomic step per worker thread
+
+    def __call__(self, i):
+        time.sleep(self.secs)
+        self.log.append(i)
+        return lambda xe, ke, index: xe[index] + ke[index]
 
 
 @pytest.mark.parametrize("kind", ["threads", "device"])
@@ -141,17 +156,20 @@ def test_every_started_subtask_is_counted(kind):
     """Rounds back to back with two workers 50 ms late, reaped at the
     fastest delta.  The thread pool cancels the late workers' queued
     subtasks, the device pool fires every deferred dispatch; either way
-    each subtask that started sliced its share exactly once, and its
+    each subtask that started prepared exactly once (the thread pool picks
+    its program and index, the device pool slices its share), and its
     preparation seconds reach the pool's tally even when it finished after
     its round was collected."""
-    rounds, delta, slice_s = 6, 2, 0.005
+    rounds, delta, prep_each_s = 6, 2, 0.005
     straggler = StragglerModel.fixed(N, 2, 0.05, seed=3)
     if kind == "threads":
         pool = ThreadWorkerPool(N, straggler, mode="threads")
+        prepared = fn = _SlowPrograms(prep_each_s)
+        shares, ke = jnp.zeros((N, 4)), jnp.zeros((N, 4))
     else:
         pool = DeviceWorkerPool(N, straggler)
-    shares, ke = _SlowShares(slice_s), [jnp.zeros(4)] * N
-    fn = lambda i: lambda x, k: x + k  # noqa: E731
+        prepared = shares = _SlowShares(prep_each_s)
+        fn, ke = (lambda i: lambda x, k: x + k), [jnp.zeros(4)] * N
     prep_s, started, cancelled, futures = 0.0, 0, 0, []
     for _ in range(rounds):
         pending = pool.submit(fn, shares, ke)
@@ -169,8 +187,8 @@ def test_every_started_subtask_is_counted(kind):
         assert cancelled > 0
     else:
         assert (cancelled, started) == (0, N * rounds)
-    assert len(shares.sliced) == started
-    assert prep_s >= started * slice_s
+    assert len(prepared.log) == started
+    assert prep_s >= started * prep_each_s
 
 
 def test_reset_zeroes_round_counters():
