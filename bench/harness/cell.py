@@ -23,7 +23,7 @@ import time
 
 import numpy as np
 
-from . import geometry, reference, traffic
+from . import geometry, reference, spec, traffic
 from . import trace as tracing
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -74,20 +74,50 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
+def _json_pool(pool):
+    """A program's pool in the configuration's JSON form: an int as it is,
+    a mapping, dataclass or named tuple as a dict (a key with a trailing
+    ``_``, as a keyword such as ``global`` has to be spelled, loses it)."""
+    if dataclasses.is_dataclass(pool) and not isinstance(pool, type):
+        pool = dataclasses.asdict(pool)
+    elif hasattr(pool, "_asdict"):
+        pool = pool._asdict()
+    if isinstance(pool, dict):
+        return {k.rstrip("_"): v for k, v in pool.items()}
+    return pool
+
+
+def _program_entry(layer) -> dict:
+    """A program's layer descriptor as a configuration entry: every
+    vocabulary key it carries, read by attribute (``from_`` for the keyword
+    ``from``); a key it lacks takes the vocabulary's default."""
+    entry = {}
+    for key in spec.LAYER_KEYS:
+        for attr in (key, key + "_"):
+            if hasattr(layer, attr):
+                entry[key] = getattr(layer, attr)
+                break
+    if "pool" in entry:
+        entry["pool"] = _json_pool(entry["pool"])
+    return entry
+
+
 def check_program_layers(config: dict) -> None:
     """The program's own layer table for ``arch`` must be the config's
-    (the reference runs the config's; this keeps the two the same)."""
+    layer graph, every vocabulary key with its default filled in (the
+    reference runs the config's; this keeps the two the same)."""
     from repro.models.cnn import CNN_SPECS
 
-    hw, layers = CNN_SPECS[config["arch"]]
-    mine = [(l["name"], l["in_ch"], l["out_ch"], l["kernel"],
-             l.get("stride", 1), l.get("padding", 0), l.get("pool", 1))
-            for l in config["layers"]]
-    theirs = [(l.name, l.in_ch, l.out_ch, l.kernel, l.stride, l.padding,
-               l.pool) for l in layers]
+    arch = config["arch"]
+    if arch not in CNN_SPECS:
+        raise ValueError(f"the program has no arch {arch!r} (it has "
+                         f"{sorted(CNN_SPECS)})")
+    _, layers = CNN_SPECS[arch]
+    mine = spec.nodes(config)
+    theirs = spec.nodes({"layers": [_program_entry(l) for l in layers]})
     if mine != theirs:
-        raise ValueError(f"{config['arch']}: the program's layers {theirs} "
-                         f"differ from the configuration's {mine}")
+        raise ValueError(f"{arch}: the program's layers {theirs} differ "
+                         f"from the configuration's {mine}")
 
 
 @dataclasses.dataclass
@@ -158,7 +188,8 @@ class ServedCell:
         """Compile the small eager programs that batch assembly runs on the
         host, for every batch size an open loop can bring: stacking k
         images, padding k rows to their bucket, and the equal-depth merge
-        of two fragments (slice, concatenate, pad) at every layer's input.
+        of two fragments (slice, concatenate, pad) at every distinct input
+        shape of the layer graph's entries.
         ``CodedServer.warmup`` covers only the full-bucket programs; these
         would otherwise compile inside the window the first time a batch
         of that size appears.  Only shapes are shared with the program:
@@ -183,8 +214,8 @@ class ServedCell:
 
         img = self.images[0]
         outs = [pad(jnp.stack([img] * k, axis=0)) for k in range(1, top + 1)]
-        shapes = [img.shape] + [(g.out_ch, g.next_hw, g.next_hw)
-                                for g in geometry.layers(self.config)[:-1]]
+        shapes = dict.fromkeys((g.in_ch, g.in_hw, g.in_hw)
+                               for g in geometry.layers(self.config))
         for shape in shapes:
             full = {b: jnp.zeros((b,) + shape, img.dtype) for b in buckets}
             rows = {k: full[bucket_of(k)][tuple(
@@ -302,7 +333,8 @@ class ServedCell:
         """Under the trace, before the window: one round of every (layer,
         bucket) in its own ``bench_map/<layer>/<bucket>`` host span, every
         subtask waited for, so the trace ties each compiled worker program
-        to its geometry (``trace.reduce``)."""
+        to its geometry (``trace.reduce``).  Each entry is fed the
+        collected output its ``from`` names, kept until its last reader."""
         import concurrent.futures
 
         import jax
@@ -316,16 +348,21 @@ class ServedCell:
         except (AttributeError, KeyError) as err:
             log(f"bench: map pass skipped, the cluster lacks {err}")
             return
+        graph = spec.nodes(self.config)
+        last_read = {n.src: i for i, n in enumerate(graph)}
         for bucket in pipe.bucket_sizes:
-            x = jnp.zeros((bucket,) + pipe.input_shape, pipe.input_dtype)
-            for idx in range(len(pipe.specs)):
+            outs = {spec.INPUT: jnp.zeros((bucket,) + pipe.input_shape,
+                                          pipe.input_dtype)}
+            for idx, node in enumerate(graph):
                 with jax.profiler.TraceAnnotation(
                         f"{tracing.MAP_SPAN}{idx}/{bucket}"):
-                    rnd = dispatch(idx, x, self.model)
+                    rnd = dispatch(idx, outs[node.src], self.model)
                     concurrent.futures.wait(
                         list(rnd.pending.futures.values()))
                     jax.block_until_ready(list(rnd.pending.results.values()))
-                    x, _ = collect(rnd)
+                    outs[node.name], _ = collect(rnd)
+                if last_read[node.src] == idx:
+                    del outs[node.src]
 
     # -- after the window ----------------------------------------------------
     def close(self) -> None:

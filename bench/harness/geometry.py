@@ -1,9 +1,9 @@
 """Operations and bytes of a coded ConvL stack, from its geometry alone.
 
 The yardstick for every roofline and utilization the benchmark reports.
-It reads only the configuration file (layers, input size, n, k_a, k_b),
-never the program, so the work counted stays the same whatever computes
-it.  Conventions (FCDCC, arXiv 2411.01579, Sec. IV):
+It reads only the configuration file (layer graph, input size, n, k_a,
+k_b), never the program, so the work counted stays the same whatever
+computes it.  Conventions (FCDCC, arXiv 2411.01579, Sec. IV):
 
   * uncoded ConvL: ``N * H' * W' * C * K^2`` multiply-adds per image;
   * coded worker subtask: APCP slices the padded input into ``k_a`` row
@@ -14,13 +14,16 @@ it.  Conventions (FCDCC, arXiv 2411.01579, Sec. IV):
     ``ell = 1 if k == 1 else 2``;
   * one round of a layer dispatches ``n`` such subtasks.
 
-A FLOP is a multiply or an add (2 per multiply-add).  Bytes are the least
+A FLOP is a multiply or an add (2 per multiply-add); only the convs count
+(no shift, residual add, activation or pool).  Bytes are the least
 traffic of one subtask: read its coded inputs and coded filters once,
 write its outputs once.
 """
 from __future__ import annotations
 
 import dataclasses
+
+from . import spec
 
 
 @dataclasses.dataclass(frozen=True)
@@ -31,7 +34,7 @@ class LayerGeometry:
     kernel: int
     stride: int
     padding: int
-    pool: int
+    pool: int | spec.Pool
     in_hw: int  # square input side this layer sees
 
     @property
@@ -40,8 +43,13 @@ class LayerGeometry:
 
     @property
     def next_hw(self) -> int:
-        """Side of the next layer's input (after the max-pool, floor)."""
-        return self.out_hw // self.pool if self.pool > 1 else self.out_hw
+        """Side of this layer's output, after its pool (floor)."""
+        p = self.pool
+        if isinstance(p, int):
+            return self.out_hw // p if p > 1 else self.out_hw
+        if p.op == "avg":
+            return 1
+        return (self.out_hw + 2 * p.padding - p.size) // p.stride + 1
 
 
 def ell(k: int) -> int:
@@ -49,15 +57,25 @@ def ell(k: int) -> int:
 
 
 def layers(config: dict) -> list[LayerGeometry]:
-    """The configuration's ConvL stack with each layer's input side."""
-    hw = int(config["input_hw"])
+    """The configuration's ConvLs in pipeline order, each with the input
+    side of what its ``from`` names: that entry's output after its pool,
+    or the image.  ``ValueError`` where channels or sides do not meet."""
+    graph = spec.nodes(config)
+    shape = {spec.INPUT: (graph[0].in_ch, int(config["input_hw"]))}
     out = []
-    for spec in config["layers"]:
-        g = LayerGeometry(spec["name"], spec["in_ch"], spec["out_ch"],
-                          spec["kernel"], spec.get("stride", 1),
-                          spec.get("padding", 0), spec.get("pool", 1), hw)
+    for n in graph:
+        ch, hw = shape[n.src]
+        g = LayerGeometry(n.name, n.in_ch, n.out_ch, n.kernel, n.stride,
+                          n.padding, n.pool, hw)
+        if n.in_ch != ch:
+            raise ValueError(f"layer {n.name!r}: in_ch {n.in_ch}, but "
+                             f"{n.src!r} gives {ch} channels")
+        if n.add is not None and shape[n.add] != (n.out_ch, g.out_hw):
+            raise ValueError(f"layer {n.name!r}: adds {n.add!r}, (channels, "
+                             f"side) {shape[n.add]}, to its own "
+                             f"{(n.out_ch, g.out_hw)}")
         out.append(g)
-        hw = g.next_hw
+        shape[n.name] = (n.out_ch, g.next_hw)
     return out
 
 

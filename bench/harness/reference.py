@@ -1,15 +1,26 @@
-"""The plain reference: weights, images and the uncoded ConvL stack.
+"""The plain reference: weights, images and the uncoded layer graph.
 
 Imports nothing of the program.  The weights and the request images are
 made here from the seed, on the device, in one jitted call each, and the
 program under test is handed the same arrays: so the reference takes
-nothing that the program made.
+nothing that the program made.  Random streams (``prng_key``): 0 the
+filters, 1 the images, 1000 the shifts; ``traffic.rng`` takes 2, 3+, 5+
+and 10+ of its own generator.  Filters are N(0, 1) / sqrt(fan-in), one
+split key per entry in the configuration's order; shifts, only for
+entries with ``"bias": true`` and under ``"<name>.bias"``, are
+N(0, 1) x ``SHIFT_STD`` (0.1) per output channel.
 
-``forward`` is the uncoded stack in float32 at ``HIGHEST`` precision, the
-precision the configuration states (``"precision": "highest"``): conv
-(NCHW / OIHW), ReLU, then a floor ``pool x pool`` max-pool.  It runs in
-blocks of a fixed number of images, so one compiled program covers any
-number of them and its memory stays small.
+``forward`` walks the configuration's layer graph (``spec.py`` gives the
+vocabulary and its defaults) in float32 at ``HIGHEST`` precision, the
+precision the configuration states (``"precision": "highest"``).  Each
+entry computes ``pool(act(conv(x) + bias + out[add]))``: conv (NCHW /
+OIHW) of the output its ``from`` names, its shift, the residual, ReLU
+unless ``"relu": false``, then its pool: a floor ``k x k`` max-pool for
+an int, ``lax.reduce_window`` max over -inf padding, or the mean over the
+whole map.  The last entry's output is the answer; a chain runs exactly
+conv, ReLU, pool per layer.  It runs in blocks of a fixed number of
+images, so one compiled program covers any number of them and its memory
+stays small.
 
 ``forward(..., precision="high")`` is the control: the same stack with
 every conv at three bf16 passes, the nearest precision below the stated
@@ -24,6 +35,11 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from . import spec
+
+SHIFT_STREAM = 1000
+SHIFT_STD = 0.1
 
 
 def prng_key(seed: int, stream: int):
@@ -40,18 +56,29 @@ def _filter_shapes(config: dict) -> dict:
 
 
 def make_weights(config: dict, seed: int) -> dict:
-    """Per-layer OIHW filters, N(0, 1) / sqrt(fan-in), on the device."""
+    """Per-layer OIHW filters, N(0, 1) / sqrt(fan-in), and the shifts of
+    the entries with ``bias``, on the device in one jitted call."""
     shapes = _filter_shapes(config)
+    shifts = {n.name + ".bias": n.out_ch for n in spec.nodes(config)
+              if n.bias}
     dtype = jnp.dtype(config["dtype"])
 
-    @jax.jit
-    def init(key):
+    def init(key, shift_key=None):
         keys = jax.random.split(key, len(shapes))
-        return {name: jax.random.normal(k, shape, dtype)
-                / np.sqrt(shape[1] * shape[2] * shape[3])
-                for k, (name, shape) in zip(keys, shapes.items())}
+        params = {name: jax.random.normal(k, shape, dtype)
+                  / np.sqrt(shape[1] * shape[2] * shape[3])
+                  for k, (name, shape) in zip(keys, shapes.items())}
+        if shift_key is not None:
+            keys = jax.random.split(shift_key, len(shifts))
+            params.update({name: SHIFT_STD * jax.random.normal(k, (ch,),
+                                                               dtype)
+                           for k, (name, ch) in zip(keys, shifts.items())})
+        return params
 
-    return jax.block_until_ready(init(prng_key(seed, 0)))
+    keys = [prng_key(seed, 0)]
+    if shifts:
+        keys.append(prng_key(seed, SHIFT_STREAM))
+    return jax.block_until_ready(jax.jit(init)(*keys))
 
 
 def make_images(config: dict, count: int, seed: int):
@@ -82,35 +109,47 @@ def _conv(x, w, stride, padding, precision):
                        "default": jax.lax.Precision.DEFAULT}[precision])
 
 
-def _relu_pool(y, pool: int):
-    y = jnp.maximum(y, 0.0)
-    if pool == 1:
-        return y
-    h, w = y.shape[-2:]
-    h2, w2 = h - h % pool, w - w % pool
-    y = y[..., :h2, :w2]
-    return y.reshape(y.shape[:-2] + (h2 // pool, pool, w2 // pool,
-                                     pool)).max(axis=(-3, -1))
+def _pool(y, pool):
+    if isinstance(pool, int):
+        if pool == 1:
+            return y
+        h, w = y.shape[-2:]
+        h2, w2 = h - h % pool, w - w % pool
+        y = y[..., :h2, :w2]
+        return y.reshape(y.shape[:-2] + (h2 // pool, pool, w2 // pool,
+                                         pool)).max(axis=(-3, -1))
+    if pool.op == "avg":
+        return jnp.mean(y, axis=(-2, -1), keepdims=True)
+    pad = ((0, 0), (0, 0)) + ((pool.padding, pool.padding),) * 2
+    return jax.lax.reduce_window(
+        y, jnp.asarray(-jnp.inf, y.dtype), jax.lax.max,
+        (1, 1, pool.size, pool.size), (1, 1, pool.stride, pool.stride), pad)
 
 
 @functools.lru_cache(maxsize=8)
-def _stack_fn(layers: tuple, precision: str):
+def _graph_fn(graph: tuple, precision: str):
     def run(params, x):
-        for name, stride, padding, pool in layers:
-            x = _relu_pool(_conv(x, params[name], stride, padding, precision),
-                           pool)
-        return x
+        outs = {spec.INPUT: x}
+        for n in graph:
+            y = _conv(outs[n.src], params[n.name], n.stride, n.padding,
+                      precision)
+            if n.bias:
+                y = y + params[n.name + ".bias"][:, None, None]
+            if n.add is not None:
+                y = y + outs[n.add]
+            if n.relu:
+                y = jnp.maximum(y, 0.0)
+            outs[n.name] = _pool(y, n.pool)
+        return outs[graph[-1].name]
 
     return jax.jit(run)
 
 
 def forward(config: dict, params: dict, images, *, precision: str = "highest",
             block: int = 8) -> np.ndarray:
-    """The uncoded ConvL stack over ``images`` (N, C, H, W), ``block``
+    """The uncoded layer graph over ``images`` (N, C, H, W), ``block``
     images per call (the last block is zero-padded); returns host f32."""
-    layers = tuple((l["name"], l.get("stride", 1), l.get("padding", 0),
-                    l.get("pool", 1)) for l in config["layers"])
-    fn = _stack_fn(layers, precision)
+    fn = _graph_fn(spec.nodes(config), precision)
     outs = []
     for s in range(0, images.shape[0], block):
         x = images[s:s + block]
