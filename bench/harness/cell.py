@@ -334,7 +334,15 @@ class ServedCell:
         bucket) in its own ``bench_map/<layer>/<bucket>`` host span, every
         subtask waited for, so the trace ties each compiled worker program
         to its geometry (``trace.reduce``).  Each entry is fed the
-        collected output its ``from`` names, kept until its last reader."""
+        collected output its ``from`` names; an entry with an ``add`` is
+        also given, as the keyword ``add`` of
+        ``dispatch_pipeline_layer``, the whole collected ``(B, C, H, W)``
+        output of the entry its ``add`` names, which the program adds after
+        the shift and before the activation in that round's collect.  Every
+        other entry is dispatched without the keyword, so a program that
+        lacks it still serves every chain, and fails a residual graph here
+        with a ``TypeError`` naming ``add``.  An output is kept until its
+        last reader, by ``from`` or by ``add``, and dropped after it."""
         import concurrent.futures
 
         import jax
@@ -349,20 +357,27 @@ class ServedCell:
             log(f"bench: map pass skipped, the cluster lacks {err}")
             return
         graph = spec.nodes(self.config)
-        last_read = {n.src: i for i, n in enumerate(graph)}
+        reads = [[r for r in dict.fromkeys((n.src, n.add)) if r is not None]
+                 for n in graph]
+        last_read = {r: i for i, rs in enumerate(reads) for r in rs}
         for bucket in pipe.bucket_sizes:
             outs = {spec.INPUT: jnp.zeros((bucket,) + pipe.input_shape,
                                           pipe.input_dtype)}
             for idx, node in enumerate(graph):
                 with jax.profiler.TraceAnnotation(
                         f"{tracing.MAP_SPAN}{idx}/{bucket}"):
-                    rnd = dispatch(idx, outs[node.src], self.model)
+                    if node.add is None:
+                        rnd = dispatch(idx, outs[node.src], self.model)
+                    else:
+                        rnd = dispatch(idx, outs[node.src], self.model,
+                                       add=outs[node.add])
                     concurrent.futures.wait(
                         list(rnd.pending.futures.values()))
                     jax.block_until_ready(list(rnd.pending.results.values()))
                     outs[node.name], _ = collect(rnd)
-                if last_read[node.src] == idx:
-                    del outs[node.src]
+                for r in reads[idx]:
+                    if last_read[r] == idx:
+                        del outs[r]
 
     # -- after the window ----------------------------------------------------
     def close(self) -> None:

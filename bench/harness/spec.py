@@ -18,7 +18,11 @@ keys ``name``, ``in_ch``, ``out_ch``, ``kernel``, ``stride`` (default 1),
   * ``bias``: ``true`` adds a per-output-channel shift after the conv
     (batch-norm folded for inference); default ``false``;
   * ``add``: an earlier entry whose output is added after the shift and
-    before the activation (a residual); default none;
+    before the activation (a residual); default none.  The program takes
+    it as the keyword ``add`` of ``FcdccCluster.dispatch_pipeline_layer``:
+    the whole ``(B, C, H, W)`` output of the entry it names, added in that
+    round's collect.  An entry without ``add`` is dispatched without the
+    keyword;
   * ``relu``: default ``true``; ``false`` for a projection shortcut, whose
     output is only added;
   * ``pool``: an int k, a k x k non-overlapping floor max-pool (default 1,

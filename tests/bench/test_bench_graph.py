@@ -7,6 +7,7 @@ import os
 import sys
 import types
 import typing
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -377,6 +378,7 @@ class _RecordingCluster:
     def __init__(self, config, buckets):
         self.geo = geometry.layers(config)
         self.fed = []
+        self.added = []
         c = config["layers"][0]["in_ch"]
         pipe = types.SimpleNamespace(
             bucket_sizes=buckets, specs=self.geo,
@@ -384,8 +386,10 @@ class _RecordingCluster:
             input_dtype=jnp.float32)
         self.pipelines = {"m": pipe}
 
-    def dispatch_pipeline_layer(self, idx, x, model):
+    def dispatch_pipeline_layer(self, idx, x, model, add=None):
         self.fed.append((idx, x.shape, float(x.reshape(-1)[0])))
+        self.added.append(None if add is None else
+                          (idx, add.shape, float(add.reshape(-1)[0])))
         return types.SimpleNamespace(
             idx=idx, batch=x.shape[0],
             pending=types.SimpleNamespace(futures={}, results={}))
@@ -396,18 +400,100 @@ class _RecordingCluster:
                         rnd.idx + 1.0), None
 
 
-@pytest.mark.parametrize("name", ["tinyres", "alexnet-n8"])
-def test_bench_graph_map_pass_feeds_what_from_names(name):
-    cfg = _residual_config() if name == "tinyres" else _config(name)
+def _map(cfg, cluster):
     served = cells.ServedCell(cfg, {}, 1)
     served.model = "m"
-    cluster = _RecordingCluster(cfg, (1, 4))
     served.server = types.SimpleNamespace(cluster=cluster)
     served._map_pass()
-    index = {n.name: i for i, n in enumerate(spec.nodes(cfg))}
-    want = []
+
+
+@pytest.mark.parametrize("name", ["tinyres", "alexnet-n8", "vgg16-n8"])
+def test_bench_graph_map_pass_feeds_what_from_names(name):
+    cfg = _residual_config() if name == "tinyres" else _config(name)
+    cluster = _RecordingCluster(cfg, (1, 4))
+    _map(cfg, cluster)
+    graph = spec.nodes(cfg)
+    index = {n.name: i for i, n in enumerate(graph)}
+    want, added = [], []
     for bucket in (1, 4):
-        for i, (n, g) in enumerate(zip(spec.nodes(cfg), cluster.geo)):
+        for i, (n, g) in enumerate(zip(graph, cluster.geo)):
             fill = 0.0 if n.src == spec.INPUT else index[n.src] + 1.0
             want.append((i, (bucket, g.in_ch, g.in_hw, g.in_hw), fill))
+            if n.add is not None:
+                a = cluster.geo[index[n.add]]
+                added.append((i, (bucket, a.out_ch, a.next_hw, a.next_hw),
+                              index[n.add] + 1.0))
     assert cluster.fed == want
+    assert [a for a in cluster.added if a is not None] == added
+    if name == "tinyres":
+        # b1c adds the projection's output, b2c the first block's
+        assert [a[0] for a in added] == [4, 7, 4, 7]
+    else:
+        assert cluster.added == [None] * len(want)
+
+
+class _Tracked(np.ndarray):
+    """A collected output that can be watched for being dropped."""
+
+
+class _WatchingCluster(_RecordingCluster):
+    """Records, at each dispatch, which collected outputs are still held."""
+
+    def __init__(self, config, buckets):
+        super().__init__(config, buckets)
+        self.names = [n.name for n in spec.nodes(config)]
+        self.refs = {}
+        self.held = []
+
+    def dispatch_pipeline_layer(self, idx, x, model, add=None):
+        self.held.append({n for n, r in self.refs.items()
+                          if r() is not None})
+        return super().dispatch_pipeline_layer(idx, x, model, add=add)
+
+    def collect_pipeline_layer(self, rnd):
+        y, t = super().collect_pipeline_layer(rnd)
+        y = np.asarray(y).view(_Tracked)
+        self.refs[self.names[rnd.idx]] = weakref.ref(y)
+        return y, t
+
+
+def test_bench_graph_map_pass_keeps_an_add_input_to_its_add_reader():
+    """b1c is read last by b2a's ``from`` and then by b2c's ``add``: it is
+    held through b2c's dispatch and gone at the next entry's; b1proj, read
+    by b1c's ``add`` alone, is held until b1c and dropped right after."""
+    head = dict(name="head", in_ch=16, out_ch=16, kernel=1)
+    cfg = dict(_residual_config(), layers=RESIDUAL_LAYERS + [head])
+    cluster = _WatchingCluster(cfg, (2,))
+    _map(cfg, cluster)
+    assert cluster.held == [
+        set(),                   # stem
+        {"stem"},                # b1proj
+        {"stem", "b1proj"},      # b1a: the stem's last reader
+        {"b1proj", "b1a"},       # b1b
+        {"b1proj", "b1b"},       # b1c: adds b1proj, its last reader
+        {"b1c"},                 # b2a: b1c's last ``from`` reader
+        {"b1c", "b2a"},          # b2b
+        {"b1c", "b2b"},          # b2c: adds b1c, its last reader
+        {"b2c"},                 # head
+    ]
+
+
+class _ChainOnlyCluster(_RecordingCluster):
+    """A program whose dispatch has no ``add`` keyword."""
+
+    def dispatch_pipeline_layer(self, idx, x, model):
+        return super().dispatch_pipeline_layer(idx, x, model)
+
+
+@pytest.mark.parametrize("name", ["tinyres", "alexnet-n8"])
+def test_bench_graph_map_pass_needs_add_only_for_a_residual(name):
+    cfg = _residual_config() if name == "tinyres" else _config(name)
+    cluster = _ChainOnlyCluster(cfg, (1,))
+    if name == "tinyres":
+        with pytest.raises(TypeError, match="add"):
+            _map(cfg, cluster)
+        # the entries before the first residual were served
+        assert [f[0] for f in cluster.fed] == [0, 1, 2, 3]
+    else:
+        _map(cfg, cluster)
+        assert len(cluster.fed) == len(cfg["layers"])

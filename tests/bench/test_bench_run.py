@@ -260,3 +260,57 @@ def test_bench_run_exits_without_a_tpu():
     assert p.returncode != 0
     assert not any(line.startswith("{") for line in p.stdout.splitlines())
     assert "TPU" in p.stderr
+
+
+_DEVICE_POOL_RUNS = """
+import json
+import sys
+
+sys.path.insert(0, sys.argv[1])
+import jax
+import jax.numpy as jnp
+import test_bench_run as t
+from repro.runtime.devicepool import DeviceWorkerPool
+
+submits = []
+submit = DeviceWorkerPool.submit
+
+
+def counted(self, *args, **kwargs):
+    submits.append(1)
+    return submit(self, *args, **kwargs)
+
+
+DeviceWorkerPool.submit = counted
+sound = t._run("open")
+# the exchange left out: no surviving shard reaches the master device,
+# which decodes from buffers that nothing filled
+DeviceWorkerPool.gather = lambda self, arr: jax.device_put(
+    jnp.zeros(arr.shape, arr.dtype), self.master)
+broken = t._run("open")
+print(json.dumps(dict(devices=len(jax.devices()), submits=len(submits),
+                      sound=sound["correct"], broken=broken["correct"],
+                      checks=broken["checks"])))
+"""
+
+
+def test_bench_run_device_pool_without_the_exchange_is_not_correct():
+    """On four host devices the program picks the device pool, as it does
+    on four chips: the tiny cell is correct there, and is not once the
+    fastest shards are no longer moved from their devices to the master."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=(os.environ.get("XLA_FLAGS", "") +
+                          " --xla_force_host_platform_device_count=4"),
+               PYTHONPATH=os.path.join(ROOT, "src"))
+    p = subprocess.run(
+        [sys.executable, "-c", _DEVICE_POOL_RUNS,
+         os.path.dirname(os.path.abspath(__file__))],
+        capture_output=True, text=True, env=env, timeout=300, cwd=ROOT)
+    assert p.returncode == 0, p.stderr[-4000:]
+    r = json.loads(p.stdout.splitlines()[-1])
+    assert r["devices"] == 4 and r["submits"] > 0
+    assert r["sound"] is True
+    checks = r["checks"]
+    assert r["broken"] is False
+    assert checks["max_rel_err"]["value"] > checks["max_rel_err"]["limit"]
+    assert checks["unanswered"]["value"] == 0

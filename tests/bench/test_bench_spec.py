@@ -36,6 +36,11 @@ def test_bench_top_level_keys_and_sizes():
     assert BENCH["command"][1].startswith(BENCH["paths"][0] + "/")
 
 
+def test_bench_each_config_and_traffic_pair_is_one_cell():
+    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    assert len(set(pairs)) == len(pairs)
+
+
 @pytest.mark.parametrize("workload", WORKLOADS)
 def test_bench_workload_resolves_to_its_files(workload):
     cell = spec.resolve(workload, ROOT)
