@@ -13,8 +13,9 @@ Workers execute behind a pool seam (``repro.runtime.devicepool``):
     the only executor for ``mode="simulated"``);
   * ``pool="device"`` — each worker pinned to its own ``jax.Device`` from a
     1-D worker mesh (``launch.mesh.make_worker_mesh``): coded filters
-    ``device_put`` once per worker and resident, worker programs jitted per
-    device, ``submit`` = pure async dispatch onto the device queues,
+    placed once as per-device blocks and resident, worker programs jitted
+    per device, ``submit`` = one grouped placement of the round's shares
+    and async dispatch onto the device queues,
     ``collect`` = a per-array-readiness reaper keeping the fastest delta.
     Default whenever real parallelism is available (``mode="threads"`` on a
     multi-device host — e.g. ``XLA_FLAGS=--xla_force_host_platform_device_
@@ -93,12 +94,14 @@ class LayerTiming:
     # before starting, and were decoded from (delta), and seconds from
     # submit to the delta-th finish; ``prep_s`` is the workers' share
     # preparation since the previous collected round (a straggler's lands
-    # in a later round, so each started subtask counts once)
+    # in a later round, so each started subtask counts once); ``placements``
+    # the transfers that placed the round's shares on their devices
     prep_s: float = 0.0
     started: int = 0
     cancelled: int = 0
     used: int = 0
     delta_ready_s: float = 0.0
+    placements: int = 0
     # master-side span seconds of the round, by span name
     phases: dict = dataclasses.field(default_factory=dict)
 
@@ -140,7 +143,7 @@ class FcdccCluster:
     Persistent state across calls: jitted worker programs (keyed by the
     worker-program signature — per device under ``pool="device"``),
     per-layer ``CodedConv2d`` instances, and resident coded filters (from
-    ``preload_filters`` or ``load_pipeline``; per-device shards under the
+    ``preload_filters`` or ``load_pipeline``; per-device blocks under the
     device pool).
     """
 
@@ -276,7 +279,7 @@ class FcdccCluster:
         """Adopt a compiled ``CodedPipeline`` under the model namespace
         ``name``: its (already encoded, exactly once) coded filters become
         resident on this cluster's workers as ``"{name}/{layer}"`` entries —
-        under the device pool, sharded ``device_put`` once per worker
+        under the device pool, placed once as one block per worker
         device.  Several pipelines coexist on the one shared pool;
         re-registering a name replaces its pipeline and resident filters."""
         if pipeline.n != self.n:
@@ -295,14 +298,14 @@ class FcdccCluster:
             for spec, ke in zip(pipeline.specs, pipeline.coded_filters):
                 key = self._filter_code_key(spec.plan, spec.geo)
                 self._resident[f"{name}/{spec.name}"] = (key, ke, pipeline)
-                # device pool: scatter the filter shards to their workers
+                # device pool: place the filter blocks on their devices
                 # now, at load time — the paper's pre-stored deployment — so
                 # the serving hot path never pays the placement
                 impl.resident_filters(f"{name}/{spec.name}", ke)
 
     def unload_pipeline(self, name: str) -> None:
         """Evict model ``name``: its pipeline registration, resident
-        filters, and (device pool) per-device filter shards.  Jitted worker
+        filters, and (device pool) per-device filter blocks.  Jitted worker
         programs stay cached — they are keyed by program signature, shared
         across models, and a re-registration would re-trace them anyway."""
         with self._registry_lock:
@@ -429,7 +432,7 @@ class FcdccCluster:
         fn = lambda i: impl.program(pkey, layer.worker_compute, i,  # noqa: E731
                                     self._programs)
         if impl.kind == "device":
-            # filter shards live on the worker devices (identity-cached)
+            # filter blocks live on the worker devices (identity-cached)
             ke = impl.resident_filters(layer_name or "__layer", ke)
         # warm the kernel on first sight of these shapes so per-worker
         # timings measure steady state (skipped once warmed — re-running
@@ -605,9 +608,9 @@ class FcdccCluster:
 
 
 def _share_shape(ke) -> tuple:
-    """One worker's filter-shard shape (list = per-device shards, array =
+    """One worker's filter-share shape (list = per-device blocks, array =
     the stacked master copy), read without slicing on the device."""
-    return tuple(ke[0].shape if isinstance(ke, list) else ke.shape[1:])
+    return tuple(ke[0].shape[1:] if isinstance(ke, list) else ke.shape[1:])
 
 
 def _counts(impl, pending: PendingBatch, ids: list) -> dict:
@@ -616,7 +619,8 @@ def _counts(impl, pending: PendingBatch, ids: list) -> dict:
     was collected."""
     return dict(prep_s=impl.prep.take(), started=pending.started,
                 cancelled=pending.cancelled, used=len(ids),
-                delta_ready_s=pending.delta_ready_s)
+                delta_ready_s=pending.delta_ready_s,
+                placements=pending.placements)
 
 
 def run_layer_elastic(plan: FcdccPlan, geo: ConvGeometry, x, k,

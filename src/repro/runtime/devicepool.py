@@ -17,16 +17,22 @@ master/worker round:
     ``jax.Device`` from a 1-D worker mesh (``launch.mesh.make_worker_mesh``
     / ``sharding.worker_devices``): real TPU/GPU devices, or CPU host
     devices via ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` so
-    CI exercises it.  Coded filters are ``device_put`` once per worker and
-    stay resident; the worker program is jitted *per device* (its own
-    bounded trace cache, so the bounded-program contract is per-device);
-    ``submit`` is pure async dispatch — all n subtasks enqueue on their own
-    device queues with no per-call thread hop — and ``collect`` reaps the
-    fastest delta via per-array readiness (``jax.Array.is_ready``),
-    discarding late arrivals exactly like the thread pool.  Injected
-    straggler delays are honored as *delayed dispatch* (a timer defers the
-    enqueue by ``delays[i]`` — a simulated network/queueing delay ahead of
-    the subtask), so the deterministic straggler tests and experiments run
+    CI exercises it.  Worker ``i`` runs on device ``i % m`` of the
+    ``m``-device mesh, at slot ``i // m`` of that device's *block*, the
+    stacked shares of the workers pinned to it.  Coded filters are placed
+    once as per-device blocks and stay resident; ``submit`` places the
+    round's coded inputs the same way, every device's block in one grouped
+    transfer, and each worker's program selects its share from its
+    device's blocks by its slot (``core.programs.worker_share_program``,
+    jitted *per device*: its own bounded trace cache, so the
+    bounded-program contract is per-device).  The n subtasks then enqueue
+    on their own device queues with no per-call thread hop, and
+    ``collect`` reaps the fastest delta via per-array readiness
+    (``jax.Array.is_ready``), discarding late arrivals exactly like the
+    thread pool.  Injected straggler delays are honored as *delayed
+    dispatch* (a timer defers the enqueue by ``delays[i]`` — a simulated
+    network/queueing delay ahead of the subtask, whose share is already on
+    its device), so the deterministic straggler tests and experiments run
     unchanged on the device pool; with zero delays the variance you measure
     is the real per-device one.
 
@@ -51,14 +57,17 @@ propagates out of ``collect`` to the round's caller.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
-from repro.core.programs import named, worker_share_program
+from repro.core.programs import worker_share_program
 
 from .spans import WORKER_PREP, WORKER_RUN, WORKER_STRAGGLE, timed
 
@@ -115,7 +124,10 @@ class PendingBatch:
     on the device pool, when the reaper first saw its result) is live the
     same way.
     ``expected`` is the set of live workers; ``errors`` (device pool) holds
-    what a dispatch raised, for ``collect`` to re-raise.  ``collect`` fills
+    what a dispatch raised, for ``collect`` to re-raise; ``placements``
+    counts the transfers that placed the round's shares on their devices
+    (the device pool's one grouped placement; 0 where the workers read the
+    shares in place).  ``collect`` fills
     in ``cancelled`` (subtasks it cancelled before they started) and
     ``delta_ready_s`` (submit to the delta-th finish)."""
 
@@ -126,6 +138,7 @@ class PendingBatch:
     expected: set | None = None
     lock: threading.Lock | None = None
     errors: list = dataclasses.field(default_factory=list)  # guarded-by: self.lock
+    placements: int = 0  # guarded-by: submit-thread
     finish_t: list = dataclasses.field(default_factory=list)  # guarded-by: single-writer-slots
     cancelled: int = 0  # guarded-by: collect-thread
     delta_ready_s: float = float("nan")  # guarded-by: collect-thread
@@ -356,8 +369,9 @@ class ThreadWorkerPool:
 
 class DeviceWorkerPool:
     """n coded workers pinned one-per-``jax.Device`` (round-robin when the
-    mesh is smaller), with per-device resident filters and per-device jit
-    caches.  See the module docstring for the dispatch/reap model."""
+    mesh is smaller), with per-device resident filter blocks and
+    per-device jit caches.  See the module docstring for the dispatch/reap
+    model."""
 
     kind = "device"
 
@@ -377,6 +391,16 @@ class DeviceWorkerPool:
         self.straggler = straggler
         self.mesh = mesh if mesh is not None else make_worker_mesh(n, devices)
         self.devices = worker_devices(self.mesh, n)  # len n (round-robin)
+        # worker i: block i % m, slot i // m (``_place``)
+        self._block_devices = list(self.mesh.devices.flat)
+        m = len(self._block_devices)
+        self._block_sharding = NamedSharding(
+            self.mesh, PartitionSpec(None, self.mesh.axis_names))
+        # each worker's slot as a scalar on its device, made once: the
+        # program selects the worker's share with it, and a Python int
+        # would cost a host-to-device copy every subtask
+        self._slot = [jax.device_put(np.int32(i // m), self.devices[i])
+                      for i in range(n)]
         # decode runs on the master device: where the default jit places it
         self.master = jax.devices()[0]
         self.prep = PrepTally()
@@ -391,7 +415,7 @@ class DeviceWorkerPool:
         self._state_lock = threading.RLock()
         # bounded-program contract can be asserted device by device
         self._programs: dict[tuple, object] = {}  # guarded-by: self._state_lock
-        # resident filter shards: name -> (master ke ref, [per-device shard])
+        # resident filter blocks: name -> (master ke ref, [per-device block])
         # — keyed by the cluster's namespaced layer name, invalidated by
         # master-array identity so re-encoded filters are re-placed
         self._filters: dict[str, tuple] = {}  # guarded-by: self._state_lock
@@ -412,12 +436,13 @@ class DeviceWorkerPool:
 
     # -- program/filter placement ------------------------------------------
     def program(self, key: tuple, raw, i: int, jit_cache: dict = None):
+        """Worker ``i``'s device's own jitted ``worker_share_program``,
+        called as ``(xe_block, ke_block, slot)``."""
         dev = self.devices[i]
         with self._state_lock:
             fn = self._programs.get((key, dev))
             if fn is None:
-                fn = self._programs[(key, dev)] = jax.jit(
-                    named(raw, "worker"))
+                fn = self._programs[(key, dev)] = worker_share_program(raw)
             return fn
 
     def program_traces(self) -> dict:
@@ -430,18 +455,28 @@ class DeviceWorkerPool:
             out[dev] = out.get(dev, 0) + fn._cache_size()
         return out
 
+    def _place(self, stacked) -> list:
+        """The n stacked shares ``(n, ...)`` as one block per mesh device,
+        ``(slots, ...)`` with slot ``j`` of device ``d`` worker
+        ``j * m + d``'s share, placed in one grouped transfer."""
+        placed = jax.device_put(
+            _share_blocks(stacked, len(self._block_devices)),
+            self._block_sharding)
+        by_device = {s.device: s.data for s in placed.addressable_shards}
+        return [by_device[d] for d in self._block_devices]
+
+    def _pick(self, blocks: list, i: int):
+        return blocks[i % len(self._block_devices)]
+
     def resident_filters(self, name: str, ke) -> list:
-        """The per-device shard list for coded filters ``ke`` under the
+        """The per-device filter blocks of coded filters ``ke`` under the
         namespaced layer ``name`` — placed once (the paper's pre-stored
         filters), reused until ``ke`` is a different array."""
         with self._state_lock:
             ent = self._filters.get(name)
             if ent is None or ent[0] is not ke:
-                shards = [jax.device_put(ke[i], self.devices[i])
-                          for i in range(self.n)]
-                for s in shards:
-                    s.block_until_ready()
-                ent = self._filters[name] = (ke, shards)
+                blocks = jax.block_until_ready(self._place(ke))
+                ent = self._filters[name] = (ke, blocks)
             return ent[1]
 
     def drop_filters(self, prefix: str) -> None:
@@ -455,34 +490,47 @@ class DeviceWorkerPool:
         return jax.device_put(arr, self.master)
 
     def warm(self, fn, xe, ke) -> None:
-        """Compile the worker program on every live device (per-device jit
-        caches) outside the timed collect."""
-        outs = []
+        """Compile the placement and, on every device with a live worker,
+        the worker program on that device's blocks (per-device jit caches)
+        outside the timed collect.  The devices compile at once, each on a
+        thread of its own."""
+        blocks, first = self._place(xe), {}
         for i in range(self.n):
             if np.isfinite(self.straggler.delays[i]):
-                outs.append(fn(i)(
-                    jax.device_put(xe[i], self.devices[i]), _ke_of(ke, i)
-                ))
-        for o in outs:
-            o.block_until_ready()
+                first.setdefault(self.devices[i], i)
+
+        def run(i):
+            return fn(i)(self._pick(blocks, i), self._pick(ke, i),
+                         self._slot[i])
+
+        with ThreadPoolExecutor(max_workers=max(len(first), 1)) as ex:
+            jax.block_until_ready(list(ex.map(run, first.values())))
 
     # -- dispatch / reap ---------------------------------------------------
     def submit(self, fn, xe, ke, round_id: int = -1) -> PendingBatch:
+        """Place the round's shares on their devices in one grouped
+        transfer, then dispatch every live worker: now, or ``delays[i]``
+        later on a timer thread (the share is already on its device)."""
         delays = self.straggler.delays
         pending = PendingBatch.open(delays, lock=threading.Lock())
+        with timed(WORKER_PREP, round=round_id) as place:
+            blocks = self._place(xe)
+        self.prep.add(place.s)
+        pending.placements = 1
 
         def dispatch(i):
             # async: enqueues on device i's queue and returns immediately;
-            # a refused compile or placement is kept for collect to raise
-            # (a deferred dispatch runs on a timer thread, where it would
-            # otherwise vanish and leave collect waiting forever)
+            # a refused compile is kept for collect to raise (a deferred
+            # dispatch runs on a timer thread, where it would otherwise
+            # vanish and leave collect waiting forever)
             try:
                 with timed(WORKER_PREP, round=round_id, worker=i) as prep:
-                    xe_i = jax.device_put(xe[i], self.devices[i])
-                    ke_i = _ke_of(ke, i)
+                    program = fn(i)
+                    args = (self._pick(blocks, i), self._pick(ke, i),
+                            self._slot[i])
                 self.prep.add(prep.s)
                 with timed(WORKER_RUN, round=round_id, worker=i):
-                    out = fn(i)(xe_i, ke_i)
+                    out = program(*args)
             except Exception as err:
                 with pending.lock:
                     pending.errors.append(err)
@@ -565,7 +613,15 @@ class DeviceWorkerPool:
         return reaped, list(pending.worker_times), t_compute
 
 
-def _ke_of(ke, i: int):
-    """Worker i's filter shard: list = pre-placed per-device shards
-    (device pool resident filters), array = indexed master copy."""
-    return ke[i]
+@functools.partial(jax.jit, static_argnums=1)
+def _share_blocks(stacked, m: int):
+    """``(n, s0, ...)`` stacked shares laid out as ``(slots, m * s0, ...)``
+    with ``slots = ceil(n / m)``, so splitting axis 1 into ``m`` blocks
+    gives device ``d`` the shares of workers ``d, d + m, ...``.  Where ``m``
+    does not divide ``n`` the last slots repeat the first shares; no worker
+    computes them."""
+    n = stacked.shape[0]
+    slots = -(-n // m)
+    if slots * m > n:
+        stacked = jnp.concatenate([stacked, stacked[:slots * m - n]])
+    return stacked.reshape((slots, m * stacked.shape[1]) + stacked.shape[2:])

@@ -6,10 +6,11 @@ trace it sits on the same clock as the device's operations) and its
 ``perf_counter`` seconds feed the counters in ``serving.metrics``.  The
 engine's spans are flat, none opened around another, so a trace's
 coverage of an idle gap names one phase; only worker spans that run on
-the engine thread (the device pool's undelayed dispatches, the simulated
-clock) sit inside ``coded.submit``.  Spans link to the round that caused
-them through metadata (``round``, ``layer``, ``bucket``, ``worker``),
-which a trace keeps as event stats beside the bare span name.
+the engine thread (the device pool's share placement and undelayed
+dispatches, the simulated clock) sit inside ``coded.submit``.  Spans link
+to the round that caused them through metadata (``round``, ``layer``,
+``bucket``, ``worker``), which a trace keeps as event stats beside the
+bare span name.
 """
 from __future__ import annotations
 
@@ -29,7 +30,7 @@ DECODE = "coded.decode"          # decode + relu + pool
 TRANSITION = "coded.transition"  # fused partition-resident transition
 COMPLETE = "coded.complete"      # a finished batch back to its requests
 # worker threads (the engine or a timer thread on the device pool)
-WORKER_PREP = "coded.worker.prep"          # the worker's share slicing
+WORKER_PREP = "coded.worker.prep"          # share placement, program pick
 WORKER_RUN = "coded.worker.run"            # the worker program
 WORKER_STRAGGLE = "coded.worker.straggle"  # an injected straggler delay
 

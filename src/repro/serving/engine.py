@@ -641,7 +641,7 @@ class CodedServer:
             transition_s=timing.decode_s,
             prep_s=timing.prep_s, started=timing.started, used=timing.used,
             cancelled=timing.cancelled, delta_ready_s=timing.delta_ready_s,
-            phases=ph,
+            placements=timing.placements, phases=ph,
         )
         if batch.layer_idx >= len(pipe.specs):
             with timed(COMPLETE, round=ent.rnd.round,

@@ -97,7 +97,10 @@ class OverlapStats:
     The subtask counters say how much of the worker pool's work a round
     used: of the n coded subtasks, ``subtasks_started`` reached the device,
     ``subtasks_cancelled`` were cancelled before they started, and
-    ``subtasks_used`` (delta per round) were decoded from.
+    ``subtasks_used`` (delta per round) were decoded from;
+    ``share_placements`` counts the transfers that placed rounds' shares
+    on their devices (one a round on the device pool, none on the thread
+    pool).
     ``longest_phase_s`` is the longest single span of the engine thread
     (``repro.runtime.spans``; ``longest_phase`` names it), engine-wide."""
 
@@ -113,6 +116,7 @@ class OverlapStats:
     subtasks_cancelled: int = 0
     prep_s: float = 0.0         # sum: every started share preparation
     delta_ready_s: float = 0.0  # sum: submit -> delta-th worker finish
+    share_placements: int = 0   # sum: rounds' share placements
     longest_phase_s: float = 0.0
     longest_phase: str = ""
 
@@ -157,12 +161,14 @@ class MetricsCollector:
     # what ``record_phases`` keeps of a round: OverlapStats sums these
     _ROUND_FIELDS = ("dispatch_s", "worker_s", "collect_s", "transition_s",
                      "subtasks_started", "subtasks_used",
-                     "subtasks_cancelled", "prep_s", "delta_ready_s")
+                     "subtasks_cancelled", "prep_s", "delta_ready_s",
+                     "share_placements")
 
     def record_phases(self, model: str, *, dispatch_s: float, worker_s: float,
                       collect_s: float, transition_s: float,
                       prep_s: float = 0.0, started: int = 0, used: int = 0,
                       cancelled: int = 0, delta_ready_s: float = 0.0,
+                      placements: int = 0,
                       phases: dict | None = None) -> None:
         """One collected worker round's phase breakdown and subtask
         counters (engine thread); ``phases`` are its engine-thread span
@@ -170,7 +176,7 @@ class MetricsCollector:
         with self._lock:
             self._phases.setdefault(model, []).append(
                 (dispatch_s, worker_s, collect_s, transition_s, started,
-                 used, cancelled, prep_s, delta_ready_s)
+                 used, cancelled, prep_s, delta_ready_s, placements)
             )
             for name, secs in (phases or {}).items():
                 self._longest = max(self._longest, (secs, name))
@@ -203,7 +209,7 @@ class MetricsCollector:
             busy, depth = self._busy_wall_s, self._max_depth
             longest_s, longest = self._longest
         sums = {f: sum((p[k] for p in phases),
-                       0 if f.startswith("subtasks_") else 0.0)
+                       0.0 if f.endswith("_s") else 0)
                 for k, f in enumerate(self._ROUND_FIELDS)}
         return OverlapStats(
             rounds=len(phases), busy_wall_s=busy, max_depth=depth,

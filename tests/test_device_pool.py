@@ -11,7 +11,7 @@ suite's behavior identical to the thread-pool-only seed.
 Covers: threads-vs-device bit-parity (forced fastest-delta subsets) across
 the CNN archs x {lax, pallas}; fastest-delta discard under a slowed
 device; dead-device elastic re-plan; the per-device bounded-program
-contract; resident filter placement; pool resolution rules; and serving
+contract; resident filter blocks; pool resolution rules; and serving
 through the device pool.
 """
 import time
@@ -170,12 +170,16 @@ def test_filters_resident_on_worker_devices():
         impl = cluster._pool_impl()
         devs = cluster.worker_devices
         assert devs is not None and len(devs) == N
-        for spec in pipe.specs:
-            _, shards = impl._filters[f"m/{spec.name}"]
-            assert len(shards) == N
-            for i, shard in enumerate(shards):
-                assert shard.devices() == {devs[i]}
-        # unload reclaims every per-device shard of the namespace
+        m = min(N, len(jax.devices()))
+        for spec, ke in zip(pipe.specs, pipe.coded_filters):
+            _, blocks = impl._filters[f"m/{spec.name}"]
+            assert len(blocks) == m
+            for i in range(N):  # worker i: block i % m, slot i // m
+                block = blocks[i % m]
+                assert block.devices() == {devs[i]}
+                np.testing.assert_array_equal(np.asarray(block[i // m]),
+                                              np.asarray(ke[i]))
+        # unload reclaims every per-device block of the namespace
         cluster.unload_pipeline("m")
         assert not any(key.startswith("m/") for key in impl._filters)
     finally:

@@ -3,8 +3,9 @@
 Covers: every ``coded.*`` span of the engine and of the workers landing on
 the profiler's host planes with bare names and a ``round`` stat, the
 worker spans tied to an engine round; the per-round subtask counters under
-the simulated clock and under real straggler threads; ``reset`` clearing
-them; and ``GET /v1/stats`` exporting them.
+the simulated clock and under real straggler threads; the device pool's
+one share placement a round; ``reset`` clearing them; and ``GET
+/v1/stats`` exporting them.
 """
 import concurrent.futures
 import glob
@@ -120,21 +121,7 @@ def test_subtask_counters_with_straggler_threads():
     assert o.subtasks_used == pipe.specs[0].plan.delta * o.rounds
     assert o.prep_s > 0
     assert 0 < o.delta_ready_s <= o.worker_s
-
-
-class _SlowShares:
-    """Coded shares whose slicing takes ``secs`` and is logged (the device
-    pool slices each worker's share on the host)."""
-
-    def __init__(self, secs):
-        self.secs = secs
-        self.log = []  # list.append: one atomic step per worker thread
-        self.share = jnp.zeros(4)
-
-    def __getitem__(self, i):
-        time.sleep(self.secs)
-        self.log.append(i)
-        return self.share
+    assert o.share_placements == 0  # the workers read the shares in place
 
 
 class _SlowPrograms:
@@ -156,21 +143,20 @@ def test_every_started_subtask_is_counted(kind):
     """Rounds back to back with two workers 50 ms late, reaped at the
     fastest delta.  The thread pool cancels the late workers' queued
     subtasks, the device pool fires every deferred dispatch; either way
-    each subtask that started prepared exactly once (the thread pool picks
-    its program and index, the device pool slices its share), and its
+    each subtask that started picked its program exactly once, and its
     preparation seconds reach the pool's tally even when it finished after
-    its round was collected."""
+    its round was collected.  The device pool's tally also holds the
+    round's one grouped share placement."""
     rounds, delta, prep_each_s = 6, 2, 0.005
     straggler = StragglerModel.fixed(N, 2, 0.05, seed=3)
+    prepared = fn = _SlowPrograms(prep_each_s)
+    shares, ke = jnp.zeros((N, 4)), jnp.zeros((N, 4))
     if kind == "threads":
         pool = ThreadWorkerPool(N, straggler, mode="threads")
-        prepared = fn = _SlowPrograms(prep_each_s)
-        shares, ke = jnp.zeros((N, 4)), jnp.zeros((N, 4))
     else:
         pool = DeviceWorkerPool(N, straggler)
-        prepared = shares = _SlowShares(prep_each_s)
-        fn, ke = (lambda i: lambda x, k: x + k), [jnp.zeros(4)] * N
-    prep_s, started, cancelled, futures = 0.0, 0, 0, []
+        ke = pool.resident_filters("layer", ke)
+    prep_s, started, cancelled, placements, futures = 0.0, 0, 0, 0, []
     for _ in range(rounds):
         pending = pool.submit(fn, shares, ke)
         results, _, _ = pool.collect(pending, delta)
@@ -178,6 +164,7 @@ def test_every_started_subtask_is_counted(kind):
         prep_s += pool.prep.take()
         started += pending.started
         cancelled += pending.cancelled
+        placements += pending.placements
         futures += pending.futures.values()
     concurrent.futures.wait(futures)
     time.sleep(0.2)  # the device pool's last deferred dispatches
@@ -185,8 +172,10 @@ def test_every_started_subtask_is_counted(kind):
     pool.shutdown()
     if kind == "threads":
         assert cancelled > 0
+        assert placements == 0
     else:
         assert (cancelled, started) == (0, N * rounds)
+        assert placements == rounds
     assert len(prepared.log) == started
     assert prep_s >= started * prep_each_s
 
@@ -200,7 +189,7 @@ def test_reset_zeroes_round_counters():
     server.metrics.reset()
     o = server.overlap_stats()
     assert (o.rounds, o.subtasks_started, o.subtasks_used,
-            o.subtasks_cancelled) == (0, 0, 0, 0)
+            o.subtasks_cancelled, o.share_placements) == (0, 0, 0, 0, 0)
     assert (o.prep_s, o.delta_ready_s, o.longest_phase_s) == (0.0, 0.0, 0.0)
     assert o.longest_phase == ""
 
@@ -216,8 +205,26 @@ def test_stats_endpoint_carries_round_counters():
         o = server.overlap_stats()
     # the idle engine keeps timing spans; rounds have all been counted
     for field in ("subtasks_started", "subtasks_used", "subtasks_cancelled",
-                  "prep_s", "delta_ready_s"):
+                  "prep_s", "delta_ready_s", "share_placements"):
         assert overlap[field] == getattr(o, field), field
     assert overlap["subtasks_started"] > 0
     assert overlap["longest_phase"] in ENGINE_SPANS
     assert overlap["longest_phase_s"] > 0
+
+
+def test_device_pool_places_shares_once_a_round():
+    """Serving on the device pool counts one share placement per round;
+    ``GET /v1/stats`` exports the count and ``reset`` clears it."""
+    server = CodedServer(_pipeline(), StragglerModel.none(N),
+                         mode="threads", pool="device")
+    with ServingFrontend(server, port=0) as frontend:
+        _serve(server, 3)
+        with urllib.request.urlopen(f"{frontend.url}/v1/stats",
+                                    timeout=30.0) as resp:
+            overlap = json.loads(resp.read())["aggregate"]["overlap"]
+        o = server.overlap_stats()
+        assert o.rounds > 0
+        assert o.share_placements == o.rounds
+        assert overlap["share_placements"] == o.share_placements
+        server.metrics.reset()
+        assert server.overlap_stats().share_placements == 0
