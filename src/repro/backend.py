@@ -5,7 +5,7 @@
     Mosaic.  Code above ``repro.kernels`` has no switch for it, so a served
     path can never emulate its kernels on a chip.
   * ``enable_compile_cache`` — JAX's persistent compilation cache for entry
-    points (the serving CLI, the benchmarks, ``chip_smoke.py``).  Never
+    points (the serving CLI, ``benchmarks/run.py``, ``chip_smoke.py``).  Never
     called at import, so tests do not fill it.
   * ``full_f32`` — the coded programs state f32 arithmetic; on a TPU an f32
     matmul or conv left at default precision rounds its operands to bf16,

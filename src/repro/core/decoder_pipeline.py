@@ -28,7 +28,7 @@ rounds and LM decode rounds concurrently:
 ``UncodedPlan`` is the straggler-bound baseline: the same worker pool and
 worker program, weights split ``n`` ways with no redundancy, identity
 decode — every round must wait for ALL ``n`` workers, so one straggler
-bounds the token rate (what exp13 measures coded decode against).
+bounds the token rate.
 """
 from __future__ import annotations
 
@@ -576,7 +576,7 @@ class CodedDecoderPipeline:
 
     def run_round_direct(self, idx: int, x, worker_ids=None):
         """One coded GEMM round on the single-process vmapped path with an
-        explicitly forced survivor subset (tests/benchmarks)."""
+        explicitly forced survivor subset (the tests' reference)."""
         ids = tuple(sorted(self.layer_worker_ids(idx, worker_ids)))
         xe = self.encoder(idx)(x)
         sel = jnp.asarray(ids)

@@ -7,8 +7,8 @@ Pipeline (Fig. 1):
 
 Two execution paths share the same math:
   * ``run_simulated`` — vmap over the worker axis on one device; straggler
-    subsets selected explicitly (used by tests/benchmarks and by the
-    master/worker runtime in ``repro.runtime``).
+    subsets selected explicitly (used by tests, the paper reproductions
+    and the master/worker runtime in ``repro.runtime``).
   * ``run_sharded`` — ``shard_map`` over a mesh "workers" axis: each device
     computes its coded subtask, coded outputs are all-gathered (they are
     Q/n-sized each, so this is the paper's "download" phase as an ICI

@@ -234,8 +234,8 @@ class CodedPipeline:
         # pressure scales with delta x block x bucket otherwise).  None =
         # donate wherever XLA honors donation (CPU does not — it warns and
         # copies, so the CPU default keeps donation off).  Callers that
-        # re-feed the same ``outs`` array into a transition twice (paired
-        # benchmarks) must pass False.
+        # re-feed the same ``outs`` array into a transition twice must
+        # pass False.
         if donate_transitions is None:
             donate_transitions = jax.default_backend() != "cpu"
         self.donate_transitions = donate_transitions

@@ -12,7 +12,7 @@ ops layer consults when a jitted program is built.
 
 Contract with the bounded-program guarantee: **lookups never sweep**.  A
 sweep runs only through the explicit ``tune_*`` entry points (called by
-``CodedPipeline.autotune_kernels`` and ``benchmarks/exp10_kernel_roofline``);
+``CodedPipeline.autotune_kernels``);
 a cache miss at trace time just returns None and the kernel uses its
 defaults.  Tile sizes are static kernel arguments, so a tuned program is
 the same single trace per (geometry, bucket) an untuned one would be.

@@ -131,7 +131,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool, force=False, smoke_scale
     tag = f"{arch}__{shape}__{'2x16x16' if multi_pod else '16x16'}"
     if smoke_scale:
         # Smoke runs get their own cache file: a scaled-down record must
-        # never be resumed (or roofline-reported) as a production cell.
+        # never be resumed as a production cell.
         tag += f"__smoke{smoke_scale}"
     os.makedirs(RESULTS_DIR, exist_ok=True)
     out_path = os.path.join(RESULTS_DIR, tag + ".json")

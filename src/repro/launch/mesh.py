@@ -48,10 +48,3 @@ def make_worker_mesh(n: int, devices=None):
         (len(devs),), ("workers",),
         axis_types=(jax.sharding.AxisType.Auto,), devices=devs,
     )
-
-
-# TPU v5e-ish hardware constants for the roofline (per chip)
-PEAK_FLOPS_BF16 = 197e12  # FLOP/s
-HBM_BW = 819e9  # B/s
-ICI_BW = 50e9  # B/s per link (we charge the full collective wire bytes
-#               against one link — the bottleneck-link model)

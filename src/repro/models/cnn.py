@@ -4,8 +4,8 @@ Each network is a list of conv-layer geometries (the paper's experiments
 time only the ConvLs).  ``run_convls`` executes the stack either
 single-node ("naive") or — when given a plan — as a thin wrapper over the
 ``repro.core.pipeline.CodedPipeline`` engine (every ConvL coded, filters
-encoded once, batched inputs) — this drives benchmarks/exp1..exp5 and the
-coded-inference example.
+encoded once, batched inputs) — this drives the paper reproductions
+``benchmarks/exp1..exp5`` and the coded-inference example.
 """
 from __future__ import annotations
 
@@ -71,7 +71,7 @@ VGG16 = (
 
 CNN_SPECS = {"lenet5": LENET5, "alexnet": ALEXNET, "vgg16": VGG16}
 
-# reduced spatial sizes for CPU smoke runs (serve CLI, exp6, examples)
+# reduced spatial sizes for CPU smoke runs (serve CLI, examples)
 SMOKE_HW = {"lenet5": 32, "alexnet": 113, "vgg16": 56}
 
 

@@ -20,18 +20,10 @@ model coalesced back into full buckets when capacity allows.  Constructing the s
 single pipeline is the unchanged single-model API (one model named
 ``"default"``).
 
-Two execution paths share the resident pipelines:
-
-  * ``execution="cluster"`` — every layer is a full master/worker round
-    (encode, dispatch n coded subtasks via the cluster's persistent
-    per-worker pool, fastest-delta collect, decode).  Stragglers and dead
-    workers behave exactly as in ``run_pipeline``; this is what
-    ``benchmarks/exp6_serving.py`` and ``exp8_multimodel.py`` measure.
-  * ``execution="direct"`` — survivors are pre-picked from the straggler
-    model (dead workers excluded, slowest gamma dropped) and the whole
-    stack runs through ``CodedPipeline.run_prepared``: no host-side code
-    prep between layers, so decode of layer *i* overlaps encode of layer
-    *i+1* on the device queue.
+Every layer of every batch is one full master/worker round (encode,
+dispatch n coded subtasks to the cluster's worker pool, fastest-delta
+collect, decode), so stragglers and dead workers behave exactly as in
+``run_pipeline``.
 
 Batch sizes are padded to each pipeline's ``bucket_sizes``, so jit compiles
 one program per (layer, bucket) — ``warmup()`` pre-traces them all, and the
@@ -56,7 +48,6 @@ import dataclasses
 import threading
 import time
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -80,15 +71,12 @@ class _ModelState:
 
     The name -> pipeline registry lives ONLY in the cluster
     (``FcdccCluster.pipelines``, written by ``load_pipeline``); this object
-    holds the serving-side extras (the direct-mode survivor plan) and
     resolves ``pipeline`` through the cluster — so the engine and the
     cluster can never disagree about what is resident.  The fair-share
     weight likewise lives only in the ``MultiScheduler``."""
 
     name: str
     cluster: FcdccCluster
-    # direct-mode survivor plan, built lazily  # guarded-by: engine-thread
-    prepared: tuple | None = None
 
     @property
     def pipeline(self) -> CodedPipeline:
@@ -118,18 +106,15 @@ class CodedServer:
 
     def __init__(self, pipeline: CodedPipeline | None = None,
                  straggler: StragglerModel | None = None, *,
-                 mode: str = "simulated", execution: str = "cluster",
+                 mode: str = "simulated",
                  bucket_sizes=None, max_inflight: int = 2,
                  pipeline_depth: int = 2,
                  poll_interval_s: float = 0.005, model: str = "default",
                  pool: str | None = None, devices=None):
-        if execution not in ("cluster", "direct"):
-            raise ValueError(f"unknown execution mode {execution!r}")
         if not isinstance(pipeline_depth, int) or pipeline_depth < 1:
             raise ValueError(
                 f"pipeline_depth must be an integer >= 1, got {pipeline_depth!r}"
             )
-        self.execution = execution
         # round-pipelining window: how many dispatched worker rounds (of
         # any model) may be in flight at once.  1 = the classic serial
         # dispatch -> collect loop; 2+ overlaps batch A's collect + fused
@@ -163,8 +148,7 @@ class CodedServer:
     def from_cnn(cls, name: str, params: dict, n: int, *,
                  q: int | None = None, default_kab=None, input_hw=None,
                  straggler: StragglerModel | None = None,
-                 mode: str = "simulated", execution: str = "cluster",
-                 backend: str = "lax",
+                 mode: str = "simulated", backend: str = "lax",
                  bucket_sizes=None, max_inflight: int = 2,
                  pipeline_depth: int = 2,
                  model: str | None = None,
@@ -188,7 +172,7 @@ class CodedServer:
             fuse_transitions=fuse_transitions,
             pool=pool, devices=devices,
         )
-        return cls(pipeline, straggler, mode=mode, execution=execution,
+        return cls(pipeline, straggler, mode=mode,
                    max_inflight=max_inflight, pipeline_depth=pipeline_depth,
                    model=model if model is not None else name)
 
@@ -437,12 +421,7 @@ class CodedServer:
             pipe = state.pipeline
             for bucket in pipe.bucket_sizes:
                 x = jnp.zeros((bucket,) + pipe.input_shape, pipe.input_dtype)
-                if self.execution == "direct":
-                    jax.block_until_ready(
-                        pipe.run_prepared(x, self._direct_plan(state))
-                    )
-                else:
-                    self.cluster.run_pipeline(x, model=state.name)
+                self.cluster.run_pipeline(x, model=state.name)
 
     def stats(self, model: str | None = None) -> ServingStats:
         return self.metrics.stats(model)
@@ -529,12 +508,6 @@ class CodedServer:
                 if not rounds:
                     busy_t0 = time.perf_counter()
                 self._stamp_start(batch)
-                if self.execution == "direct":
-                    try:
-                        self._advance(state, batch)
-                    except Exception as err:  # degraded cluster etc.
-                        self._fail_batch(name, batch, err)
-                    break  # synchronous: back to admission, like depth 1
                 try:
                     rnd = self.cluster.dispatch_pipeline_layer(
                         batch.layer_idx, batch.x, name
@@ -649,16 +622,6 @@ class CodedServer:
                 self._complete(state, batch)
             self.metrics.note_phase(span.name, span.s)
 
-    def _advance(self, state: _ModelState, batch: ScheduledBatch) -> None:
-        """Direct execution: run one batch through the whole prepared
-        stack and complete it."""
-        pipe = state.pipeline
-        batch.x = jax.block_until_ready(
-            pipe.run_prepared(batch.x, self._direct_plan(state))
-        )
-        batch.layer_idx = len(pipe.specs)
-        self._complete(state, batch)
-
     def _complete(self, state: _ModelState, batch: ScheduledBatch) -> None:
         self.scheduler.retire(state.name, batch)
         y = np.asarray(batch.x)
@@ -678,24 +641,3 @@ class CodedServer:
                 batch_real=batch.real,
                 model=state.name,
             ))
-
-    # -- direct-mode survivor pre-pick ---------------------------------------
-    def _direct_plan(self, state: _ModelState):
-        """The ``prepare`` plan over pre-picked survivors: dead workers
-        excluded, remaining sorted by injected delay (fastest first) so each
-        layer decodes from the delta best.  Cached per model — every batch
-        reuses it until the straggler model changes, or until the resident
-        pipeline under this name is replaced (the cache holds the pipeline
-        reference itself and compares by identity — not ``id()``, whose
-        values CPython reuses after GC — so a plan prepared against old
-        encode/decode matrices can never serve the replacement)."""
-        delays = self.cluster.straggler.delays
-        pipe = state.pipeline
-        key = tuple(np.asarray(delays).tolist())
-        if (state.prepared is None or state.prepared[0] is not pipe
-                or state.prepared[1] != key):
-            alive = [i for i in range(self.cluster.n)
-                     if np.isfinite(delays[i])]
-            alive.sort(key=lambda i: (delays[i], i))
-            state.prepared = (pipe, key, pipe.prepare(alive))
-        return state.prepared[2]

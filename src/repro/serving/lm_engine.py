@@ -45,7 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.decoder_pipeline import CodedDecoderPipeline
-from repro.runtime import ClusterDegraded, FcdccCluster, StragglerModel
+from repro.runtime import FcdccCluster, StragglerModel
 
 from .scheduler import MultiScheduler, RequestHandle, ScheduledBatch
 
@@ -98,25 +98,19 @@ class CodedLMServer:
     """Continuous-batching greedy-decode server over one coded decoder
     pipeline.  ``submit()`` is thread-safe and returns a ``RequestHandle``
     whose ``result()`` is the generated token array.  Use as a context
-    manager or ``start()``/``shutdown()``.
-
-    ``execution="cluster"`` (default) runs every GEMM round through the
-    master/worker runtime; ``execution="direct"`` runs the single-process
-    vmapped path (optionally with ``worker_ids`` forcing a survivor
-    subset) — no cluster, useful for tests and parity baselines.
+    manager or ``start()``/``shutdown()``.  Every GEMM round runs through
+    the master/worker runtime.
     """
 
     def __init__(self, pipeline: CodedDecoderPipeline,
                  straggler: StragglerModel | None = None, *,
                  cluster: FcdccCluster | None = None,
                  scheduler: MultiScheduler | None = None,
-                 mode: str = "simulated", execution: str = "cluster",
+                 mode: str = "simulated",
                  model: str = "lm", max_prompt: int = 16,
                  slots: int | None = None, max_inflight: int | None = None,
-                 worker_ids=None, pool: str | None = None, devices=None,
+                 pool: str | None = None, devices=None,
                  poll_interval_s: float = 0.005):
-        if execution not in ("cluster", "direct"):
-            raise ValueError(f"unknown execution mode {execution!r}")
         if pipeline.bucket_sizes is None:
             raise ValueError("pipeline needs bucket_sizes for serving")
         if max_prompt < 1 or max_prompt >= pipeline.max_len:
@@ -126,26 +120,22 @@ class CodedLMServer:
             )
         self.pipeline = pipeline
         self.model = model
-        self.execution = execution
         self.max_prompt = int(max_prompt)
         self.slots = int(slots if slots is not None else pipeline.max_batch)
         if self.slots < pipeline.max_batch:
             raise ValueError(
                 f"slots={self.slots} < largest bucket {pipeline.max_batch}"
             )
-        self.worker_ids = worker_ids
+        self._owns_cluster = cluster is None
+        if cluster is None:
+            cluster = FcdccCluster(
+                pipeline.specs[0].plan, straggler, mode=mode,
+                backend=pipeline.backend,
+                pool=pool if pool is not None else pipeline.pool,
+                devices=devices if devices is not None else pipeline.devices,
+            )
         self.cluster = cluster
-        self._owns_cluster = cluster is None and execution == "cluster"
-        if execution == "cluster":
-            if self.cluster is None:
-                self.cluster = FcdccCluster(
-                    pipeline.specs[0].plan, straggler, mode=mode,
-                    backend=pipeline.backend,
-                    pool=pool if pool is not None else pipeline.pool,
-                    devices=devices if devices is not None
-                    else pipeline.devices,
-                )
-            self.cluster.load_pipeline(pipeline, model)
+        self.cluster.load_pipeline(pipeline, model)
         self.scheduler = scheduler if scheduler is not None else MultiScheduler()
         self.scheduler.add_model(
             model, pipeline.pad_to_bucket, max_batch=pipeline.max_batch,
@@ -192,7 +182,7 @@ class CodedLMServer:
                 raise err
             self._thread = None
             self.scheduler.cancel_all(RuntimeError("server shut down"))
-        if self._owns_cluster and self.cluster is not None:
+        if self._owns_cluster:
             self.cluster.shutdown()
 
     def __enter__(self) -> "CodedLMServer":
@@ -290,15 +280,9 @@ class CodedLMServer:
             step_pos = jnp.asarray(pos[:b])
             t0 = time.perf_counter()
             try:
-                if self.execution == "cluster":
-                    logits, nxt, new_cache = pipe.run_decode_step_cluster(
-                        self.cluster, tokens, cache, step_pos,
-                        model=self.model,
-                    )
-                else:
-                    logits, nxt, new_cache = pipe.run_decode_step_direct(
-                        tokens, cache, step_pos, self.worker_ids
-                    )
+                logits, nxt, new_cache = pipe.run_decode_step_cluster(
+                    self.cluster, tokens, cache, step_pos, model=self.model,
+                )
                 nxt = np.asarray(jax.block_until_ready(nxt))
             except Exception as err:  # ClusterDegraded, kernel failure, ...
                 # mid-step failure leaves cache/coded state inconsistent for

@@ -3,10 +3,10 @@
 Every request that flows through the ``CodedServer`` leaves one
 ``RequestRecord`` (arrival -> batch start -> finish, tagged with its
 model); ``MetricsCollector`` aggregates them into a ``ServingStats`` with
-queue-wait / execute / end-to-end percentiles and images/s throughput —
-the numbers ``benchmarks/exp6_serving.py`` compares against the
-sequential ``run_pipeline`` baseline.  Multi-model servers get the same
-stats *per model* (``stats(model=...)`` / ``per_model_stats()``) while
+queue-wait / execute / end-to-end percentiles and images/s throughput
+(``bench/`` reads its scheduler metrics, ``queue_wait_p95_ms`` and
+``mean_batch``, from them).  Multi-model servers get the same stats
+*per model* (``stats(model=...)`` / ``per_model_stats()``) while
 the aggregate view stays exactly the single-model one; equal-depth batch
 merges are counted per model too (``count_coalesced``).
 """
@@ -246,7 +246,7 @@ class MetricsCollector:
 
     def stats(self, model: str | None = None) -> ServingStats:
         """Aggregate stats — over every model (``model=None``, the
-        single-model view exp6 prints) or one model's requests only."""
+        single-model view) or one model's requests only."""
         recs = self.records(model)
         merges = self.coalesced(model)
         if not recs:

@@ -262,11 +262,11 @@ def _images(count, hw=16, c=2):
             for _ in range(count)]
 
 
-@pytest.mark.parametrize("execution", ["cluster", "direct"])
-def test_serving_fused_dead_worker(execution):
+@pytest.mark.parametrize("mode", ["simulated", "threads"])
+def test_serving_fused_dead_worker(mode):
     """End-to-end serving over fused transitions under the dead-worker
-    straggler model: results match the round-trip pipeline, traces stay
-    bounded."""
+    straggler model, on the simulated clock and on the thread pool:
+    results match the round-trip pipeline, traces stay bounded."""
     params = _stack_params(STACK)
     specs = plan_layers(STACK, 16, 6, default_kab=(2, 4))
     ref = CodedPipeline(specs, params)
@@ -274,8 +274,7 @@ def test_serving_fused_dead_worker(execution):
                           fuse_transitions=True)
     delays = np.zeros(6)
     delays[2] = np.inf  # dead worker
-    server = CodedServer(fused, StragglerModel(delays), mode="simulated",
-                         execution=execution)
+    server = CodedServer(fused, StragglerModel(delays), mode=mode)
     server.warmup()
     xs = _images(5)
     with server:

@@ -119,12 +119,11 @@ def test_straggler_serving(smoke, refs):
         assert list(got) == want
 
 
-def test_direct_execution_forced_subset(smoke, refs):
-    """execution='direct' with a forced survivor subset: no cluster spun
-    up, same tokens."""
-    srv = CodedLMServer(_pipe(smoke), execution="direct",
-                        worker_ids=(1, 3), max_prompt=MAX_PROMPT)
-    assert srv.cluster is None
+def test_dead_workers_served_from_survivors(smoke, refs):
+    """Workers 0 and 2 dead: every round decodes from survivors (1, 3),
+    same tokens."""
+    st = StragglerModel(np.array([np.inf, 0.0, np.inf, 0.0]))
+    srv = CodedLMServer(_pipe(smoke), st, max_prompt=MAX_PROMPT)
     with srv:
         out = srv.generate(PROMPTS[0], GENS[0])
     assert list(out) == refs[0]
